@@ -8,12 +8,9 @@ files are skipped.
 
 Usage:  python -m benchmarks.dryrun_sweep [--multi-pod] [--arch A] [--shape S]
 """
-import os
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
-                           + os.environ.get("XLA_FLAGS", ""))
-
 import argparse
 import json
+import os
 import sys
 import time
 import traceback
@@ -30,6 +27,8 @@ def main() -> int:
     ap.add_argument("--force", action="store_true")
     args = ap.parse_args()
 
+    from repro.launch import simulate_host_devices
+    simulate_host_devices(512)
     from repro.configs import ASSIGNED_ARCHS, INPUT_SHAPES, get_shape
     from repro.launch.dryrun import run_one
 

@@ -300,10 +300,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if args.schedules:
         return run_schedules(smoke=args.smoke, out=args.out)
-    # heavy XLA mode: the forced device count must precede any jax init
-    os.environ["XLA_FLAGS"] = (
-        "--xla_force_host_platform_device_count=512 "
-        + os.environ.get("XLA_FLAGS", ""))
+    # heavy XLA mode: the simulated device count must precede any jax init
+    from repro.launch import simulate_host_devices
+    simulate_host_devices(512)
     return run()
 
 

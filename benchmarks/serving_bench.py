@@ -250,14 +250,14 @@ def live_section(print_fn=print) -> dict:
 
     from repro.configs import get_config
     from repro.core.plans import get_plan
-    from repro.launch.mesh import make_host_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models import Model
     from repro.serve import ContinuousEngine, Engine, Request
 
     cfg = dataclasses.replace(get_config("llama3.2-3b").reduced(),
                               vocab_size=512)
     model = Model(cfg)
-    mesh = make_host_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with jax.set_mesh(mesh):
         params = model.init(jax.random.key(0))
     rng = np.random.default_rng(SEED)

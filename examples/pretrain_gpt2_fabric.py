@@ -28,10 +28,10 @@ ap.add_argument("--layers", type=int, default=8)
 ap.add_argument("--d-model", type=int, default=512)
 args = ap.parse_args()
 
-os.environ["XLA_FLAGS"] = (
-    f"--xla_force_host_platform_device_count={args.devices} "
-    + os.environ.get("XLA_FLAGS", ""))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+from repro.launch import simulate_host_devices  # noqa: E402
+
+simulate_host_devices(args.devices)
 
 import dataclasses
 
@@ -42,6 +42,7 @@ from repro.configs.base import TrainConfig
 from repro.core.pipeline import pipeline_mesh
 from repro.core.plans import get_plan
 from repro.data import Loader, Tokenizer, build_dataset, synthetic_wikipedia
+from repro.launch.mesh import make_mesh
 from repro.models import Model
 from repro.train import model_flops_per_step, train
 
@@ -60,9 +61,8 @@ def main():
     loader = Loader(ds, global_batch=args.batch, seed=0)
     plan = get_plan(args.plan)
     n = args.devices
-    base = jax.make_mesh((max(n // 4, 1), min(n, 2), 2),
-                         ("pod", "data", "model"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
+    base = make_mesh((max(n // 4, 1), min(n, 2), 2),
+                     ("pod", "data", "model"))
     mesh = pipeline_mesh(base, 2) if plan.pipeline else base
     tcfg = TrainConfig(learning_rate=6e-4, warmup_steps=20,
                        total_steps=args.steps, microbatches=4)

@@ -17,7 +17,7 @@ from repro.configs import get_config
 from repro.configs.base import TrainConfig
 from repro.core.plans import get_plan
 from repro.data import Loader, Tokenizer, build_dataset, synthetic_wikipedia
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import Model
 from repro.serve import Engine
 from repro.train import train
@@ -39,7 +39,7 @@ def main():
     cfg = dataclasses.replace(get_config("llama3.2-3b").reduced(),
                               n_layers=4, vocab_size=tok.vocab_size)
     model = Model(cfg)
-    mesh = make_host_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     loader = Loader(ds, global_batch=8, seed=0)
     tcfg = TrainConfig(learning_rate=1e-3, warmup_steps=10,
                        total_steps=args.steps)

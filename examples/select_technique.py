@@ -53,11 +53,10 @@ if args.live and args.topology and args.topology != "line3":
     ap.error("--live --topology currently supports line3 (single-GPU "
              "sites, so the staged mesh fits forced host devices)")
 
-if args.live:
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={args.devices} "
-        + os.environ.get("XLA_FLAGS", ""))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+if args.live:
+    from repro.launch import simulate_host_devices
+    simulate_host_devices(args.devices)
 
 from repro.configs import get_config
 from repro.core.costmodel import PAPER_CLUSTERS, paper_workload
@@ -181,7 +180,7 @@ def live():
     from repro.core.pipeline import pipeline_mesh
     from repro.data import (Loader, Tokenizer, build_dataset,
                             synthetic_wikipedia)
-    from repro.launch.mesh import make_host_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models import Model
     from repro.train import model_flops_per_step, train
 
@@ -195,8 +194,7 @@ def live():
         plan = get_plan("shard_zero" if technique == "shard" else technique)
         both = placement is None or len(placement.sites) > 1
         n = args.devices if both else args.devices // 2
-        base = make_host_mesh((max(n // 4, 1), 2, 2),
-                              ("pod", "data", "model"))
+        base = make_mesh((max(n // 4, 1), 2, 2), ("pod", "data", "model"))
         mesh = pipeline_mesh(base, 2) if plan.pipeline else base
         loader = Loader(ds, global_batch=8, seed=0)
         res = train(Model(cfg), plan, mesh,

@@ -16,7 +16,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core.plans import get_plan
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import Model
 from repro.serve import Engine
 
@@ -33,7 +33,7 @@ def main():
 
     cfg = get_config(args.arch).reduced()
     model = Model(cfg)
-    mesh = make_host_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with jax.set_mesh(mesh):
         params = model.init(jax.random.key(0))
 

@@ -1,9 +1,7 @@
 """Reproduction of "Performance of Small Language Model Pretraining on
 FABRIC: An Empirical Study" grown toward a production-scale jax system.
 
-Importing any ``repro`` package installs the jax version-compat shims
-(repro.compat) so the modern-API codebase also runs on jax 0.4.x.
+Importing ``repro`` imports no jax, so a launcher can still choose its
+platform and device count (``repro.launch.simulate_host_devices``) after
+``import repro``.
 """
-from repro import compat as _compat  # noqa: F401  (installs jax shims)
-
-_compat.install()

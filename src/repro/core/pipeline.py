@@ -25,9 +25,8 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
-from repro.compat import NATIVE_SHARD_MAP
 from repro.core.costmodel import parse_schedule
 from repro.core.plans import Plan, STAGE_AXIS
 
@@ -101,7 +100,8 @@ def pipeline_mesh(devices_mesh: Mesh, n_stages: int,
         elif order != (0,):
             raise ValueError("stage_order given but mesh has no pod axis")
     devs = devices.reshape(n_stages, (pod * data) // n_stages, model)
-    return jax.sharding.Mesh(devs, (STAGE_AXIS, "data", "model"))
+    return Mesh(devs, (STAGE_AXIS, "data", "model"),
+                axis_types=(AxisType.Auto,) * 3)
 
 
 def stack_length(cfg, stack) -> int:
@@ -364,17 +364,9 @@ def make_pipeline_loss(model, mesh: Mesh, n_micro: int, *,
     cfg = model.cfg
     n_stages = mesh.shape[STAGE_AXIS]
     kind, virt = parse_schedule(schedule)
-    # Manual axes of the pipeline region.  The stage axis always is; on
-    # jax 0.4.x — whose SPMD partitioner CHECK-fails on partial-auto
-    # shard_map (repro.compat.NATIVE_SHARD_MAP, docs/architecture.md) —
-    # size-1 auto axes are promoted to manual so a degenerate
-    # (stage, 1, 1) mesh compiles as a fully-manual region, which that
-    # partitioner handles fine.  A size-1 axis is unsharded either way,
-    # so the promotion never changes semantics.
+    # the stage axis is the only manual axis of the pipeline region;
+    # data/model stay auto so GSPMD applies the Shard rules per stage
     manual = {STAGE_AXIS}
-    if not NATIVE_SHARD_MAP:
-        manual |= {a for a in mesh.axis_names
-                   if a != STAGE_AXIS and mesh.shape[a] == 1}
 
     def loss_fn(params, batch):
         x, positions, _ = model._embed_inputs(params, batch)
@@ -416,9 +408,7 @@ def make_pipeline_loss(model, mesh: Mesh, n_micro: int, *,
         stack_spec = jax.tree.map(lambda _: P(STAGE_AXIS), stack)
         mask_args = () if layer_valid is None else (layer_valid,)
         mask_specs = () if layer_valid is None else (P(STAGE_AXIS),)
-        # stage id as a stage-sharded input rather than lax.axis_index:
-        # axis_index lowers to partition-id, which the jax-0.4.x SPMD
-        # partitioner rejects inside partial-auto shard_map regions.
+        # stage id as a stage-sharded input: one row per stage
         stage_ids = jnp.arange(n_stages, dtype=jnp.int32)
         # per-stage local chunk length (layers a single run_stack call
         # scans): the padded chunk under a gather, the equal block else

@@ -42,13 +42,7 @@ def _set_moe_dispatch(model: Model, plan: Plan, mesh: Mesh,
     global token sort otherwise all-gathers [T, d] per MoE layer.  Not
     under Pipeshard (the stage axis is already manual there)."""
     import dataclasses
-    from repro.compat import NATIVE_SHARD_MAP
     if model.cfg.family != "moe":
-        return
-    if not NATIVE_SHARD_MAP:
-        # per-data-shard dispatch needs partial-auto shard_map, which the
-        # jax-0.4.x SPMD partitioner rejects — fall back to the (slower,
-        # mathematically identical) global dispatch path
         return
     axes = () if plan.pipeline else plan.batch_axes(mesh, global_batch)
     e_axis = ""

@@ -1,8 +1,10 @@
 """Jit'd wrappers: layout/padding glue between model code ([B, S, H, D]
 activations) and the Pallas kernels ([B, H, S, D] MXU-aligned tiles).
 
-``interpret`` defaults to True off-TPU so the kernels execute (and are
-tested) on CPU; on TPU backends the real kernels are emitted.
+``interpret=None`` means "interpret on the CPU": the kernels execute (and
+are tested) there through the Pallas interpreter, and every other backend
+gets the compiled kernels.  ``_default_interpret`` is the one place that
+makes this choice.
 """
 from __future__ import annotations
 
@@ -17,7 +19,7 @@ from repro.kernels import quantized as _q
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def _pad_axis(x, mult: int, axis: int):
@@ -134,9 +136,10 @@ def flash_attention_int8kv(q, k_q, k_scale, v_q, v_scale, *, valid=None,
     qT = _pad_axis(_pad_axis(q.transpose(0, 2, 1, 3), block_q, 2), 128, 3)
     kT = _pad_axis(_pad_axis(k_q.transpose(0, 2, 1, 3), block_k, 2), 128, 3)
     vT = _pad_axis(_pad_axis(v_q.transpose(0, 2, 1, 3), block_k, 2), 128, 3)
-    ksT = _pad_axis(k_scale.transpose(0, 2, 1), block_k, 2)
-    vsT = _pad_axis(v_scale.transpose(0, 2, 1), block_k, 2)
-    validp = _pad_axis(valid.astype(jnp.float32), block_k, 1)  # pad => dead
+    ksT = _pad_axis(k_scale.transpose(0, 2, 1)[:, :, None], block_k, 3)
+    vsT = _pad_axis(v_scale.transpose(0, 2, 1)[:, :, None], block_k, 3)
+    validp = _pad_axis(valid.astype(jnp.float32)[:, None], block_k,
+                       2)                                 # pad => dead
     o = _q.flash_attention_int8kv_bhsd(
         qT, kT, ksT, vT, vsT, validp, causal=causal, window=window,
         block_q=min(block_q, qT.shape[2]), block_k=min(block_k, kT.shape[2]),
@@ -212,5 +215,7 @@ def rmsnorm(x, weight, *, eps: float = 1e-5, block_rows: int = 256,
             interpret: bool | None = None):
     """Fused RMSNorm (kernels/rmsnorm.py)."""
     from repro.kernels import rmsnorm as _rn
+    if interpret is None:
+        interpret = _default_interpret()
     return _rn.rmsnorm(x, weight, eps=eps, block_rows=block_rows,
                        interpret=interpret)

@@ -58,7 +58,7 @@ def _int8_matmul_kernel(xq_ref, xs_ref, wq_ref, ws_ref, o_ref, acc_scr, *,
         xq_ref[...], wq_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32)                  # [bm, bn] int32
     # one absmax scale per (row-block, K-block) x (K-block, col-block)
-    # pair => the int32 partial dequantizes with a single scalar.
+    # pair => the int32 partial dequantizes with one [1, 1] factor.
     acc_scr[...] += prod.astype(jnp.float32) * (xs_ref[0, 0] * ws_ref[0, 0])
 
     @pl.when(kk == n_k_blocks - 1)
@@ -78,20 +78,23 @@ def int8_matmul_blocked(xq, xs, wq, ws, *, block_m: int = 128,
     assert xs.shape == (nm, nk) and ws.shape == (nk, nn), (xs.shape, ws.shape)
 
     kernel = functools.partial(_int8_matmul_kernel, n_k_blocks=nk)
+    # each scale is its own trailing [1, 1] tile: a TPU block's last two
+    # dims must be (8, 128)-aligned or span the array's, so a (1, 1)
+    # block of the [nm, nk] scale matrix itself does not lower
     return pl.pallas_call(
         kernel,
         grid=(nm, nn, nk),
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (i, k)),
+            pl.BlockSpec((1, 1, 1, 1), lambda i, j, k: (i, k, 0, 0)),
             pl.BlockSpec((block_k, block_n), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (k, j)),
+            pl.BlockSpec((1, 1, 1, 1), lambda i, j, k: (k, j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
-    )(xq, xs, wq, ws)
+    )(xq, xs[:, :, None, None], wq, ws[:, :, None, None])
 
 
 def _int8kv_flash_kernel(q_ref, kq_ref, ks_ref, vq_ref, vs_ref, valid_ref,
@@ -108,19 +111,20 @@ def _int8kv_flash_kernel(q_ref, kq_ref, ks_ref, vq_ref, vs_ref, valid_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0].astype(jnp.float32) * scale            # [bq, d]
-    # dequant-in-kernel: int8 payload x per-token fp32 absmax scale
-    k = kq_ref[0, 0].astype(jnp.float32) \
-        * ks_ref[0, 0].reshape(block_k, 1)                 # [bk, d]
-    v = vq_ref[0, 0].astype(jnp.float32) \
-        * vs_ref[0, 0].reshape(block_k, 1)                 # [bk, dv]
+    # dequant-in-kernel: a key's (value's) per-token scale multiplies its
+    # score column (probability column), so the [1, bk] scale rows
+    # broadcast over queries and are never transposed into columns
+    k = kq_ref[0, 0].astype(jnp.float32)                   # [bk, d]
+    v = vq_ref[0, 0].astype(jnp.float32)                   # [bk, dv]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [bq, bk]
+    s = s * ks_ref[0, 0]                                   # [1, bk] scales
 
     q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32,
                                                     (block_q, block_k), 0)
     k_pos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32,
                                                     (block_q, block_k), 1)
-    mask = valid_ref[0].reshape(1, block_k) > 0            # dynamic validity
+    mask = valid_ref[0] > 0                                # [1, bk] validity
     if causal:
         mask &= q_pos >= k_pos
     if window:
@@ -134,7 +138,8 @@ def _int8kv_flash_kernel(q_ref, kq_ref, ks_ref, vq_ref, vs_ref, valid_ref,
     l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
     m_scr[...] = m_new
     acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p * vs_ref[0, 0], v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
     @pl.when(kj == n_kv_blocks - 1)
     def _finalize():
@@ -148,9 +153,11 @@ def flash_attention_int8kv_bhsd(q, kq, ks, vq, vs, valid, *,
                                 scale: float | None = None,
                                 interpret: bool = False):
     """q: [B, H, Sq, D] fp; kq/vq: [B, KV, Sk, D*] int8 with per-token
-    scales ks/vs: [B, KV, Sk] fp32; valid: [B, Sk] fp32 (>0 = key is
-    live — carries both pad masking and the decode ring-cache fill
-    state, so it may be traced).  Returns [B, H, Sq, Dv] in q.dtype."""
+    scales ks/vs: [B, KV, 1, Sk] fp32; valid: [B, 1, Sk] fp32 (>0 = key
+    is live — carries both pad masking and the decode ring-cache fill
+    state, so it may be traced).  The scales and the validity keep Sk on
+    the lane axis behind a unit sublane axis, so their blocks tile.
+    Returns [B, H, Sq, Dv] in q.dtype."""
     B, H, Sq, D = q.shape
     _, KV, Sk, Dv = vq.shape
     group = H // KV
@@ -173,13 +180,13 @@ def flash_attention_int8kv_bhsd(q, kq, ks, vq, vs, valid, *,
                          lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_k, D),
                          lambda b, h, i, j: (b, h // group, j, 0)),
-            pl.BlockSpec((1, 1, block_k),
-                         lambda b, h, i, j: (b, h // group, j)),
+            pl.BlockSpec((1, 1, 1, block_k),
+                         lambda b, h, i, j: (b, h // group, 0, j)),
             pl.BlockSpec((1, 1, block_k, Dv),
                          lambda b, h, i, j: (b, h // group, j, 0)),
-            pl.BlockSpec((1, 1, block_k),
-                         lambda b, h, i, j: (b, h // group, j)),
-            pl.BlockSpec((1, block_k), lambda b, h, i, j: (b, j)),
+            pl.BlockSpec((1, 1, 1, block_k),
+                         lambda b, h, i, j: (b, h // group, 0, j)),
+            pl.BlockSpec((1, 1, block_k), lambda b, h, i, j: (b, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, Dv),
                                lambda b, h, i, j: (b, h, i, 0)),

@@ -25,10 +25,8 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
 
 
 def rmsnorm(x, weight, *, eps: float = 1e-5, block_rows: int = 256,
-            interpret: bool | None = None):
+            interpret: bool = False):
     """x: [..., d]; weight: [d].  Rows are tiled into VMEM blocks."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     orig_shape = x.shape
     d = x.shape[-1]
     rows = 1

@@ -1,9 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
-                           + os.environ.get("XLA_FLAGS", ""))
-# ^ MUST precede every other import: jax locks the device count at first
-# init, and the production meshes below need 512 placeholder devices.
-
 """Multi-pod dry-run: prove every (architecture × input shape × mesh)
 combination lowers AND compiles, and extract the roofline terms.
 
@@ -11,7 +5,8 @@ For each combination this builds the plan-sharded step (train_step for
 train_4k, prefill_step for prefill_32k, serve_step for decode shapes —
 ONE token against a seq_len KV cache), lowers it against
 ShapeDtypeStruct inputs (zero allocation), compiles for the 16x16
-single-pod mesh (and the 2x16x16 multi-pod mesh with --multi-pod), prints
+single-pod mesh (and the 2x16x16 multi-pod mesh with --multi-pod) on 512
+simulated CPU devices (``main`` sets them up before jax starts), prints
 ``compiled.memory_analysis()`` / ``cost_analysis()`` and writes the
 roofline JSON consumed by benchmarks/ and EXPERIMENTS.md.
 """
@@ -128,8 +123,6 @@ def run_one(arch: str, shape_name: str, plan_name: str, *,
         print(f"lower {t_lower:.1f}s compile {t_compile:.1f}s")
         print("memory_analysis:", mem)
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):   # jax 0.4.x: list per device
-            cost = cost[0] if cost else {}
         keys = ("flops", "bytes accessed")
         print("cost_analysis:", {k: cost.get(k) for k in keys})
     roof = rl.from_compiled(
@@ -161,6 +154,8 @@ def main() -> int:
     ap.add_argument("--json-out", default=None)
     args = ap.parse_args()
 
+    from repro.launch import simulate_host_devices
+    simulate_host_devices(512)
     from repro.configs import get_shape
     plan = args.plan or ("shard_zero"
                          if get_shape(args.shape).kind == "train" else "shard")

@@ -20,21 +20,25 @@ from __future__ import annotations
 from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-from repro.compat import make_mesh as _compat_make_mesh
 from repro.core.topology import Topology
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices=None) -> Mesh:
+    """The one mesh constructor: every axis is Auto, so the plans'
+    sharding rules steer GSPMD.  (``jax.make_mesh`` defaults to Explicit
+    axes, under which the embedding gather raises ``ShardingTypeError``.)
+    ``devices`` defaults to all local devices."""
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _compat_make_mesh(shape, axes)
-
-
-def make_host_mesh(shape, axes) -> Mesh:
-    """Small explicit meshes for tests (host devices)."""
-    return _compat_make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 # --------------------------------------------------------------------- #
@@ -76,7 +80,7 @@ def make_topology_mesh(topo: Topology,
     if len(devs) < n:
         raise ValueError(f"topology selection needs {n} devices, "
                          f"have {len(devs)}")
-    return _compat_make_mesh(shape, axes, devices=devs[:n])
+    return make_mesh(shape, axes, devices=devs[:n])
 
 
 def placement_pipeline_mesh(topo: Topology, placement, *,

@@ -11,7 +11,6 @@ device count, which locks at first jax init.
 """
 import argparse
 import json
-import os
 import sys
 
 
@@ -28,9 +27,8 @@ def main() -> None:
     ap.add_argument("--steps", type=int, default=2)
     args = ap.parse_args()
 
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={args.devices} "
-        + os.environ.get("XLA_FLAGS", ""))
+    from repro.launch import simulate_host_devices
+    simulate_host_devices(args.devices)
 
     import dataclasses
 
@@ -43,6 +41,7 @@ def main() -> None:
     from repro.core.pipeline import pipeline_mesh
     from repro.core.plans import PLANS, get_plan
     from repro.core.steps import build_train_step
+    from repro.launch.mesh import make_mesh
     from repro.models import Model
     from repro.models.registry import abstractify, input_specs
     from repro.optim import init_adamw
@@ -66,7 +65,7 @@ def main() -> None:
 
     n = args.devices
     assert n % 4 == 0
-    base = jax.make_mesh((n // 4, 2, 2), ("pod", "data", "model"))
+    base = make_mesh((n // 4, 2, 2), ("pod", "data", "model"))
 
     results = {}
     for plan_name in plan_names:

@@ -20,7 +20,6 @@ steps lost, recovery seconds) for scripted consumers.
 """
 import argparse
 import json
-import os
 
 
 def parse_gpus(spec: str):
@@ -84,9 +83,8 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
 
     if args.devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices} "
-            + os.environ.get("XLA_FLAGS", ""))
+        from repro.launch import simulate_host_devices
+        simulate_host_devices(args.devices)
 
     import dataclasses
     import time

@@ -27,9 +27,7 @@ Two modes, one JSON report on stdout:
     checkpoint exactly.
 
 Must run in its own process: ``--devices``/site count forces the XLA
-host platform device count, which locks at first jax init.  Pipeline
-meshes here are fully manual (stage, 1, 1), so this runs even on
-jax 0.4.x (repro.compat.NATIVE_SHARD_MAP).
+host platform device count, which locks at first jax init.
 """
 import argparse
 import json
@@ -77,9 +75,8 @@ def main() -> None:
 
     src_sites, dst_sites = _sites(args.src_sites), _sites(args.dst_sites)
     n_sites = max([2] + [s + 1 for s in src_sites + dst_sites])
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={n_sites} "
-        + os.environ.get("XLA_FLAGS", ""))
+    from repro.launch import simulate_host_devices
+    simulate_host_devices(n_sites)
 
     import dataclasses
 

@@ -93,8 +93,6 @@ def from_compiled(compiled, *, arch: str, shape: str, mesh_name: str,
                   crosses_pod: bool = False,
                   hlo_text: Optional[str] = None) -> Roofline:
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):      # older API returned [dict]
-        cost = cost[0]
     text = hlo_text if hlo_text is not None else compiled.as_text()
     pod_size = n_devices // 2 if crosses_pod else 0
     coll = collective_bytes_with_trips(text, pod_size=pod_size)

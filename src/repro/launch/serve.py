@@ -8,7 +8,6 @@ fixed-batch by default, continuous batching with ``--continuous``.
         --trace 12x8..32 --batch 3 --gen 8
 """
 import argparse
-import os
 
 
 def parse_trace(spec: str, max_prompt: int):
@@ -42,7 +41,9 @@ def main() -> None:
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--plan", default="shard", choices=sorted(PLANS),
                     help="registered parallelism plan (core/plans.py)")
-    ap.add_argument("--devices", type=int, default=0)
+    ap.add_argument("--devices", type=int, default=0,
+                    help="simulate N host CPU devices (0 = use the real "
+                         "devices)")
     ap.add_argument("--mesh", default="1,1")
     ap.add_argument("--batch", type=int, default=4,
                     help="fixed batch rows / continuous decode slots")
@@ -64,17 +65,17 @@ def main() -> None:
     if args.trace and not args.continuous:
         ap.error("--trace only applies with --continuous")
 
+    from repro.launch import enable_compile_cache, simulate_host_devices
     if args.devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices} "
-            + os.environ.get("XLA_FLAGS", ""))
+        simulate_host_devices(args.devices)
+    enable_compile_cache()
 
     import jax
     import numpy as np
 
     from repro.configs import get_config
     from repro.core.plans import get_plan
-    from repro.launch.mesh import make_host_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models import Model
     from repro.serve import ContinuousEngine, Engine, Request
 
@@ -84,7 +85,7 @@ def main() -> None:
     model = Model(cfg)
     shape = tuple(int(x) for x in args.mesh.split(","))
     axes = ("pod", "data", "model")[-len(shape):]
-    mesh = make_host_mesh(shape, axes)
+    mesh = make_mesh(shape, axes)
     with jax.set_mesh(mesh):
         params = model.init(jax.random.key(0))
 

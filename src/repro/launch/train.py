@@ -4,15 +4,16 @@
         --arch llama3.2-3b --reduced --plan shard_zero \
         --devices 8 --mesh 2,2,2 --steps 100
 
-On a real TPU slice drop --devices (jax discovers the topology) and pass
---mesh to match it; --reduced serves the smoke variant for CPU runs.
+``--devices N`` simulates N CPU devices; without it the real devices are
+used, so on a TPU host drop --devices and pass --mesh to match the chips.
+--reduced trains the smoke variant (and the tokenizer's vocabulary); at
+full size the config's own vocabulary is kept.
 """
 import argparse
-import os
 import sys
 
 
-def main() -> None:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="llama3.2-3b")
     ap.add_argument("--reduced", action="store_true",
@@ -24,7 +25,8 @@ def main() -> None:
                          "device-count override, so the choices are "
                          "never a stale hand-kept list)")
     ap.add_argument("--devices", type=int, default=0,
-                    help="force N host devices (0 = use real devices)")
+                    help="simulate N host CPU devices (0 = use the real "
+                         "devices)")
     ap.add_argument("--mesh", default="1,1",
                     help="mesh shape, e.g. 2,2,2 for (pod,data,model)")
     ap.add_argument("--stages", type=int, default=2,
@@ -40,13 +42,12 @@ def main() -> None:
     ap.add_argument("--vocab", type=int, default=2048)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    if args.devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices} "
-            + os.environ.get("XLA_FLAGS", ""))
 
+def run(args: argparse.Namespace):
+    """Build the corpus, model, mesh and plan from ``args`` and train.
+    Returns ``(cfg, TrainResult)``."""
     import dataclasses
 
     from repro.configs import get_config
@@ -55,7 +56,7 @@ def main() -> None:
     from repro.core.plans import get_plan
     from repro.data import (Loader, Tokenizer, build_dataset, load_text_dir,
                             synthetic_wikipedia)
-    from repro.launch.mesh import make_host_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models import Model
     from repro.train import model_flops_per_step, train
 
@@ -65,14 +66,19 @@ def main() -> None:
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    cfg = dataclasses.replace(cfg, vocab_size=tok.vocab_size,
+    # full size keeps the published vocabulary (the tied embedding and the
+    # logits matmul are a large share of the work); the token ids only
+    # have to fit inside it
+    vocab = tok.vocab_size if args.reduced \
+        else max(cfg.vocab_size, tok.vocab_size)
+    cfg = dataclasses.replace(cfg, vocab_size=vocab,
                               max_seq_len=max(cfg.max_seq_len, args.seq))
     ds = build_dataset(texts, tok, seq_len=args.seq)
     loader = Loader(ds, global_batch=args.batch, seed=args.seed)
 
     shape = tuple(int(x) for x in args.mesh.split(","))
     axes = ("pod", "data", "model")[-len(shape):]
-    base = make_host_mesh(shape, axes)
+    base = make_mesh(shape, axes)
     plan = get_plan(args.plan)      # KeyError lists the registry's plans
     mesh = pipeline_mesh(base, args.stages) if plan.pipeline else base
 
@@ -81,14 +87,25 @@ def main() -> None:
                        microbatches=args.microbatches)
     model = Model(cfg)
     print(f"{cfg.name} [{cfg.family}] {cfg.param_count() / 1e6:.1f}M params "
-          f"| plan={args.plan} mesh={dict(zip(axes, shape))}")
+          f"vocab={cfg.vocab_size} | plan={args.plan} "
+          f"mesh={dict(zip(axes, shape))}")
     res = train(model, plan, mesh, tcfg, loader, steps=args.steps,
                 log_every=max(args.steps // 10, 1),
                 ckpt_dir=args.ckpt_dir)
     flops = model_flops_per_step(cfg, args.batch * args.seq)
     print(f"done: loss {res.losses[0]:.3f} -> {res.losses[-1]:.3f}; "
           f"{res.tflops(flops):.4f} TFLOP/s avg")
+    return cfg, res
+
+
+def main(argv=None) -> None:
+    args = parse_args(argv)
+    from repro.launch import enable_compile_cache, simulate_host_devices
+    if args.devices:
+        simulate_host_devices(args.devices)
+    enable_compile_cache()
+    run(args)
 
 
 if __name__ == "__main__":
-    main()
+    main(sys.argv[1:])

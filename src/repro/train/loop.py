@@ -27,10 +27,16 @@ class TrainResult:
     losses: List[float] = field(default_factory=list)
     step_times: List[float] = field(default_factory=list)
     metrics_last: Dict[str, float] = field(default_factory=dict)
+    compile_s: float = 0.0          # lowering + compiling the step
+    # device bytes the compiled step needs per device (arguments +
+    # outputs + temporaries - donated aliases); None if not reported
+    step_bytes: Optional[int] = None
+    params: Any = None              # the trained state, laid out by the plan
+    opt_state: Any = None
 
     @property
     def avg_step_time(self) -> float:
-        times = self.step_times[1:] or self.step_times  # drop compile step
+        times = self.step_times[1:] or self.step_times  # drop warm-up step
         return float(np.mean(times)) if times else float("nan")
 
     def tflops(self, model_flops_per_step: float) -> float:
@@ -73,22 +79,40 @@ def train(model: Model, plan: Plan, mesh, tcfg: TrainConfig, loader, *,
     """
     cfg = model.cfg
     with jax.set_mesh(mesh):
-        if params is None:
-            params = model.init(jax.random.key(tcfg.seed))
-        if opt_state is None:
-            opt_state = init_adamw(params)
+        key = jax.random.key(tcfg.seed)
         first = loader.batch_at(start_step)
-        p_shapes = abstractify(params)
+        p_shapes = abstractify(params) if params is not None \
+            else jax.eval_shape(model.init, key)
         b_shapes = abstractify(first)
         step_fn, sh = build_train_step(model, plan, mesh, tcfg,
                                        params_shapes=p_shapes,
                                        batch_shapes=b_shapes,
                                        stage_layers=stage_layers,
                                        schedule=schedule)
-        params = jax.device_put(params, sh["params"])
-        opt_state = jax.device_put(opt_state, sh["opt"])
+        # fresh state is created in place, already laid out by the plan,
+        # so no device ever holds a whole unsharded copy first
+        if params is None:
+            params = jax.jit(model.init, out_shardings=sh["params"])(key)
+        else:
+            params = jax.device_put(params, sh["params"])
+        if opt_state is None:
+            opt_state = jax.jit(init_adamw,
+                                out_shardings=sh["opt"])(params)
+        else:
+            opt_state = jax.device_put(opt_state, sh["opt"])
 
         result = TrainResult()
+        # compile ahead of the loop, so that no step time includes it
+        t0 = time.perf_counter()
+        step_fn = step_fn.lower(params, opt_state,
+                                jax.device_put(first, sh["batch"])).compile()
+        result.compile_s = time.perf_counter() - t0
+        mem = step_fn.memory_analysis()
+        if mem is not None:
+            result.step_bytes = (mem.argument_size_in_bytes
+                                 + mem.output_size_in_bytes
+                                 + mem.temp_size_in_bytes
+                                 - mem.alias_size_in_bytes)
         metrics: Dict[str, Any] = {}
         flops = model_flops_per_step(
             cfg, first["tokens"].shape[0] * first["tokens"].shape[1]
@@ -118,4 +142,5 @@ def train(model: Model, plan: Plan, mesh, tcfg: TrainConfig, loader, *,
         result.metrics_last = {k: float(v) for k, v in metrics.items()}
         if ckpt_dir:
             save_checkpoint(ckpt_dir, steps, params, opt_state)
+    result.params, result.opt_state = params, opt_state
     return result

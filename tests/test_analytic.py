@@ -86,8 +86,8 @@ def test_explicit_groups_and_pairs():
 def test_pipeline_mesh_construction():
     import jax
     from repro.core.pipeline import pipeline_mesh, validate_stages
-    from repro.launch.mesh import make_host_mesh
-    base = make_host_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    base = make_mesh((1, 1), ("data", "model"))
     m = pipeline_mesh(base, 1)
     assert m.shape["stage"] == 1
     # stage must divide the stack length

@@ -142,8 +142,8 @@ def test_placement_validates_stage_layers():
 
 
 def test_pipeline_mesh_accepts_weighted_splits():
-    from repro.launch.mesh import make_host_mesh
-    base = make_host_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    base = make_mesh((1, 1), ("data", "model"))
     mesh = pipeline_mesh(base, 1, stage_layers=(24,))
     assert mesh.shape["stage"] == 1
     with pytest.raises(ValueError, match="entries"):

@@ -52,7 +52,7 @@ def test_real_module_scaling_with_depth():
     from functools import partial
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((1,), ("model",))
 
     def make(n):

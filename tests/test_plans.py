@@ -9,13 +9,6 @@ import sys
 import numpy as np
 import pytest
 
-from repro.compat import NATIVE_SHARD_MAP
-
-needs_partial_auto = pytest.mark.skipif(
-    not NATIVE_SHARD_MAP,
-    reason="pipeshard needs partial-auto shard_map; the jax-0.4.x SPMD "
-           "partitioner rejects it (repro.compat.NATIVE_SHARD_MAP)")
-
 
 def _run_plan_check(env, extra_args=()):
     cmd = [sys.executable, "-m", "repro.launch.plan_check",
@@ -28,7 +21,6 @@ def _run_plan_check(env, extra_args=()):
 
 
 @pytest.mark.slow
-@needs_partial_auto
 def test_all_plans_equivalent_dense(subproc_env):
     from repro.core.plans import PLANS
     res = _run_plan_check(subproc_env)
@@ -64,7 +56,6 @@ def test_plans_equivalent_ssm(subproc_env):
 
 
 @pytest.mark.slow
-@needs_partial_auto
 def test_pipeshard_four_stages(subproc_env):
     """4-stage pipeline (stage absorbs the whole 'pod'+'data' axes)."""
     res = _run_plan_check(subproc_env, ["--plans", "data,pipeshard", "--layers", "8"])

@@ -293,10 +293,10 @@ def test_engine_int8_kv(tiny_model):
     """End-to-end: the Engine carries the quantized cache through the
     compiled prefill/serve steps and generates the same greedy tokens."""
     from repro.core.plans import get_plan
-    from repro.launch.mesh import make_host_mesh
+    from repro.launch.mesh import make_mesh
     from repro.serve import Engine
     model, params = tiny_model
-    mesh = make_host_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rng = np.random.default_rng(0)
     prompts = np.asarray(rng.integers(4, 400, (2, 12)), np.int32)
     out_fp = Engine(model, get_plan("data"), mesh, batch_size=2,

@@ -8,8 +8,7 @@ Host-side tests run the canonical <-> staged-view mappers directly
 (``repro.train.reshard``); the slow tests drive the full train →
 checkpoint → reshard → resume path through ``repro.launch
 .reshard_check`` subprocesses (forced host device counts lock at first
-jax init).  The (stage, 1, 1) pipeline meshes there are fully manual,
-so everything runs even on jax 0.4.x (repro.compat.NATIVE_SHARD_MAP).
+jax init).
 """
 import json
 import subprocess

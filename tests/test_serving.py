@@ -278,13 +278,13 @@ def test_ring_valid_per_slot_masks():
 def serve_setup():
     import jax
     from repro.configs import get_config
-    from repro.launch.mesh import make_host_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models import Model
 
     cfg = dataclasses.replace(get_config("llama3.2-3b").reduced(),
                               vocab_size=512)
     model = Model(cfg)
-    mesh = make_host_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with jax.set_mesh(mesh):
         params = model.init(jax.random.key(0))
     return model, mesh, params
@@ -338,13 +338,13 @@ def test_continuous_ssm_exact_prefill_bit_exact():
     import jax
     from repro.configs import get_config
     from repro.core.plans import get_plan
-    from repro.launch.mesh import make_host_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models import Model
     from repro.serve import ContinuousEngine, Engine, Request
 
     cfg = get_config("falcon-mamba-7b").reduced()
     model = Model(cfg)
-    mesh = make_host_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with jax.set_mesh(mesh):
         params = model.init(jax.random.key(1))
     rng = np.random.default_rng(11)

@@ -13,7 +13,7 @@ from repro.configs import get_config
 from repro.configs.base import ShapeConfig, TrainConfig
 from repro.core.plans import get_plan
 from repro.data import Loader, Tokenizer, build_dataset, synthetic_wikipedia
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import Model
 from repro.serve import Engine
 from repro.train import train
@@ -33,7 +33,7 @@ def tiny_setup():
 def test_pretraining_reduces_loss(tiny_setup):
     cfg, tok, ds = tiny_setup
     loader = Loader(ds, global_batch=8, seed=0)
-    mesh = make_host_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     res = train(Model(cfg), get_plan("data"), mesh,
                 TrainConfig(warmup_steps=5, total_steps=40), loader,
                 steps=25, log_every=0)
@@ -45,7 +45,7 @@ def test_pretraining_reduces_loss(tiny_setup):
 def test_checkpoint_resume_continues(tiny_setup, tmp_path):
     cfg, tok, ds = tiny_setup
     loader = Loader(ds, global_batch=8, seed=0)
-    mesh = make_host_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     model = Model(cfg)
     tcfg = TrainConfig(warmup_steps=2, total_steps=20)
     train(model, get_plan("data"), mesh, tcfg, loader, steps=5,
@@ -65,7 +65,7 @@ def test_checkpoint_resume_continues(tiny_setup, tmp_path):
 @pytest.mark.slow
 def test_engine_generates(tiny_setup):
     cfg, tok, ds = tiny_setup
-    mesh = make_host_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     model = Model(cfg)
     with jax.set_mesh(mesh):
         params = model.init(jax.random.key(0))
@@ -121,7 +121,7 @@ def test_grad_accum_matches_full_batch(tiny_setup):
     from repro.optim import init_adamw
     cfg, tok, ds = tiny_setup
     model = Model(cfg)
-    mesh = make_host_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     from repro.data import Loader
     loader = Loader(ds, global_batch=8, seed=0)
     batch = loader.batch_at(0)
