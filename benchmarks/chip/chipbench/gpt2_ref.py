@@ -1,0 +1,318 @@
+"""Plain GPT-2 in float32: the reference that decides ``correct``.
+
+It imports nothing of the program and takes nothing the program made.
+It draws the weights from the seed itself, by the initialisation the
+configuration states (truncated normal within 3 standard deviations:
+std 1/sqrt(fan-in) for projections, 0.02 for the embeddings; zero
+biases, unit layer-norm scales), with the same key splits, so that both
+sides start from the same numbers.  Every matrix product runs at
+``Precision.HIGHEST``.
+
+``precision="float8"`` is the control: every matrix product takes its
+operands rounded to float8 e4m3 with one absmax scale per tensor, as a
+change to fp8 matmuls would.  Gradients pass the rounding straight
+through.
+"""
+from __future__ import annotations
+
+import math
+from functools import partial
+from typing import Any, Dict, List, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+HIGHEST = jax.lax.Precision.HIGHEST
+F8_MAX = 448.0          # largest finite float8_e4m3fn
+
+
+def _f8(x):
+    scale = F8_MAX / jnp.maximum(jnp.max(jnp.abs(x)), 1e-30)
+    scale = jax.lax.stop_gradient(scale)
+    q = (x * scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) / scale
+    return x + jax.lax.stop_gradient(q - x)
+
+
+def _mm(precision: str, spec: str, a, b):
+    if precision == "float8":
+        a, b = _f8(a), _f8(b)
+    elif precision != "float32":
+        raise ValueError(f"unknown reference precision {precision!r}")
+    return jnp.einsum(spec, a, b, precision=HIGHEST)
+
+
+def dims(cfg: Dict[str, Any]) -> Tuple[int, int, int, int, int, int]:
+    d, h = cfg["n_embd"], cfg["n_head"]
+    return (cfg["n_layer"], d, h, d // h, cfg["n_inner"],
+            cfg["vocab_size"])
+
+
+def init(cfg: Dict[str, Any], key) -> Dict[str, Any]:
+    """Float32 weights drawn from ``jax.random.key(seed)`` (call under
+    ``jit``)."""
+    L, d, H, hd, f, V = dims(cfg)
+    tn = partial(jax.random.truncated_normal, lower=-3.0, upper=3.0)
+
+    def dense(k, shape, fan_in):
+        return (1.0 / math.sqrt(fan_in)) * tn(k, shape=shape)
+
+    def norm():
+        return {"scale": jnp.ones((d,)), "bias": jnp.zeros((d,))}
+
+    def block(k):
+        r = jax.random.split(k, 4)
+        m = jax.random.split(r[2], 3)
+        a = jax.random.split(r[3], 4)
+        return {
+            "norm1": norm(), "norm2": norm(),
+            "mlp": {"w_up": dense(m[0], (d, f), d),
+                    "b_up": jnp.zeros((f,)),
+                    "w_down": dense(m[1], (f, d), f),
+                    "b_down": jnp.zeros((d,))},
+            "attn": {"wq": dense(a[0], (d, H, hd), d),
+                     "wk": dense(a[1], (d, H, hd), d),
+                     "wv": dense(a[2], (d, H, hd), d),
+                     "wo": dense(a[3], (H, hd, d), H * hd),
+                     "bq": jnp.zeros((H, hd)), "bk": jnp.zeros((H, hd)),
+                     "bv": jnp.zeros((H, hd)), "bo": jnp.zeros((d,))},
+        }
+
+    r = jax.random.split(key, 8)
+    return {
+        "embed": {"table": 0.02 * tn(r[0], shape=(V, d))},
+        "final_norm": norm(),
+        "pos_embed": {"table": 0.02 * tn(r[3], shape=(cfg["n_positions"],
+                                                       d))},
+        "layers": jax.vmap(block)(jax.random.split(r[4], L)),
+    }
+
+
+def _ln(x, p, eps):
+    mu = jnp.mean(x, -1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mu), -1, keepdims=True)
+    return (x - mu) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
+
+
+def _gelu(x):
+    return 0.5 * x * (1.0 + jnp.tanh(math.sqrt(2.0 / math.pi)
+                                     * (x + 0.044715 * x ** 3)))
+
+
+def logits(cfg: Dict[str, Any], params, tokens, precision: str = "float32",
+           remat: bool = False):
+    """[b, S] token ids -> [b, S, V] float32 logits, causal attention."""
+    L, d, H, hd, f, V = dims(cfg)
+    eps = cfg["layer_norm_epsilon"]
+    mm = partial(_mm, precision)
+    S = tokens.shape[1]
+    x = params["embed"]["table"][tokens] + params["pos_embed"]["table"][:S]
+    causal = jnp.tril(jnp.ones((S, S), bool))
+
+    def layer(x, p):
+        a = p["attn"]
+        h = _ln(x, p["norm1"], eps)
+        q = mm("bsd,dhk->bshk", h, a["wq"]) + a["bq"]
+        k = mm("bsd,dhk->bshk", h, a["wk"]) + a["bk"]
+        v = mm("bsd,dhk->bshk", h, a["wv"]) + a["bv"]
+        s = mm("bshk,bthk->bhst", q, k) / math.sqrt(hd)
+        s = jnp.where(causal, s, -jnp.inf)
+        o = mm("bhst,bthk->bshk", jax.nn.softmax(s, axis=-1), v)
+        x = x + mm("bshk,hkd->bsd", o, a["wo"]) + a["bo"]
+        m = p["mlp"]
+        h = _ln(x, p["norm2"], eps)
+        u = _gelu(mm("bsd,df->bsf", h, m["w_up"]) + m["b_up"])
+        return x + mm("bsf,fd->bsd", u, m["w_down"]) + m["b_down"], None
+
+    x, _ = jax.lax.scan(jax.checkpoint(layer) if remat else layer, x,
+                        params["layers"])
+    x = _ln(x, params["final_norm"], eps)
+    return mm("bsd,vd->bsv", x, params["embed"]["table"])
+
+
+def _loss_sums(cfg, precision, params, tokens, labels):
+    """Summed next-token cross-entropy and squared log-normaliser over
+    the labels that count (>= 0)."""
+    lg = logits(cfg, params, tokens, precision, remat=True)[:, :-1]
+    lab = labels[:, 1:]
+    mask = lab >= 0
+    lse = jax.nn.logsumexp(lg, axis=-1)
+    picked = jnp.take_along_axis(lg, jnp.maximum(lab, 0)[..., None],
+                                 axis=-1)[..., 0]
+    nll = jnp.sum(jnp.where(mask, lse - picked, 0.0))
+    return nll, jnp.sum(jnp.where(mask, jnp.square(lse), 0.0))
+
+
+def _decays(path) -> bool:
+    """AdamW's weight decay skips the layer-norm scales and biases."""
+    names = [str(getattr(p, "key", "")) for p in path]
+    return not any("norm" in n for n in names)
+
+
+def leaf_norms(tree) -> Dict[str, float]:
+    """Per-leaf L2 norms, summed in float64 on the host."""
+    flat = jax.tree_util.tree_flatten_with_path(tree)[0]
+    return {jax.tree_util.keystr(p): float(np.sqrt(np.sum(np.square(
+        np.asarray(x, np.float64))))) for p, x in flat}
+
+
+def change_norms(now, before) -> Dict[str, float]:
+    """Per-leaf L2 norms of ``now - before`` (host trees of one layout),
+    one leaf at a time in float64."""
+    flat = jax.tree_util.tree_flatten_with_path(now)[0]
+    return {jax.tree_util.keystr(p): float(np.sqrt(np.sum(np.square(
+        np.asarray(a, np.float64) - np.asarray(b, np.float64)))))
+        for (p, a), b in zip(flat, jax.tree.leaves(before))}
+
+
+def grad_accumulator(cfg: Dict[str, Any], z_loss: float,
+                     precision: str = "float32"):
+    """``acc, params, tokens, labels -> acc`` with the gradient of a block
+    of rows' summed loss added to ``acc = (grads, nll, z)`` in place."""
+    def block_loss(p, tokens, labels):
+        nll, zsq = _loss_sums(cfg, precision, p, tokens, labels)
+        return nll + z_loss * zsq, (nll, zsq)
+
+    @partial(jax.jit, donate_argnums=(0,))
+    def accumulate(acc, params, tokens, labels):
+        (_, (nll, zsq)), g = jax.value_and_grad(
+            block_loss, has_aux=True)(params, tokens, labels)
+        acc_g, acc_nll, acc_z = acc
+        return jax.tree.map(jnp.add, acc_g, g), acc_nll + nll, acc_z + zsq
+
+    return accumulate
+
+
+def adamw_step(opt: Dict[str, Any]):
+    """AdamW as the configuration states it: the mean gradient clipped to
+    a global norm, bias-corrected moments, decoupled weight decay on all
+    but the layer norms.  Returns (params, clipped grads, m, v)."""
+    b1, b2, eps = opt["beta1"], opt["beta2"], opt["eps"]
+    wd, clip = opt["weight_decay"], opt["grad_clip"]
+
+    @partial(jax.jit, donate_argnums=(0, 1, 2, 3))
+    def adamw(params, grads, m, v, t, lr, n_tokens):
+        grads = jax.tree.map(lambda g: g / n_tokens, grads)
+        gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
+                             for g in jax.tree.leaves(grads)))
+        grads = jax.tree.map(
+            lambda g: g * jnp.minimum(1.0, clip / jnp.maximum(gnorm, 1e-12)),
+            grads)
+        m = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g, m, grads)
+        v = jax.tree.map(lambda v, g: b2 * v + (1 - b2) * g * g, v, grads)
+        c1, c2 = 1 - b1 ** t, 1 - b2 ** t
+
+        def upd(path, p, m, v):
+            delta = (m / c1) / (jnp.sqrt(v / c2) + eps)
+            if _decays(path):
+                delta = delta + wd * p
+            return p - lr * delta
+
+        return (jax.tree_util.tree_map_with_path(upd, params, m, v),
+                grads, m, v)
+
+    return adamw
+
+
+def train_steps(cfg: Dict[str, Any], opt: Dict[str, Any], z_loss: float,
+                seed: int, batches: Sequence[Dict[str, np.ndarray]], *,
+                precision: str = "float32", rows: int = 1,
+                keep_rows: float = 1.0) -> Dict[str, Any]:
+    """AdamW steps over ``batches`` from the seed's weights: each step's
+    loss, every leaf's norm of the first (clipped) gradient, and every
+    leaf's norm of the parameters' change over all the steps.
+
+    The gradient of a step is summed over blocks of ``rows`` rows, so the
+    reference fits beside nothing else on one chip.  ``keep_rows`` < 1
+    plants a fault: only that share of each batch's rows counts, the mean
+    taken over those.
+    """
+    params = jax.jit(partial(init, cfg))(jax.random.key(seed))
+    p0 = jax.device_get(params)
+    zeros = jax.jit(lambda t: jax.tree.map(jnp.zeros_like, t))
+    accumulate = grad_accumulator(cfg, z_loss, precision)
+    adamw = adamw_step(opt)
+    m, v = zeros(params), zeros(params)
+    losses, grad0 = [], None
+    for t, batch in enumerate(batches):
+        tokens = np.asarray(batch["tokens"])
+        labels = np.asarray(batch["labels"])
+        keep = max(1, int(round(len(tokens) * keep_rows)))
+        tokens, labels = tokens[:keep], labels[:keep]
+        n_tokens = int(np.sum(labels[:, 1:] >= 0))
+        acc = (zeros(params), jnp.float32(0), jnp.float32(0))
+        for r in range(0, keep, rows):
+            acc = accumulate(acc, params, tokens[r:r + rows],
+                             labels[r:r + rows])
+        grads, nll, zsq = acc
+        losses.append((float(nll) + z_loss * float(zsq)) / n_tokens)
+        params, grads, m, v = adamw(
+            params, grads, m, v, jnp.float32(t + 1),
+            jnp.float32(lr_at(opt, t)), jnp.float32(n_tokens))
+        if t == 0:
+            grad0 = leaf_norms(grads)
+        del grads
+    p_end = jax.device_get(params)
+    del params, m, v
+    return {"losses": losses, "grad": grad0,
+            "change": change_norms(p_end, p0)}
+
+
+def lr_at(opt: Dict[str, Any], step: int) -> float:
+    """Learning rate of step ``step`` (0-based): linear warm-up, then
+    constant or cosine decay to a tenth."""
+    warm = min(1.0, (step + 1) / max(opt["warmup_steps"], 1))
+    if opt["schedule"] == "constant":
+        decay = 1.0
+    elif opt["schedule"] == "cosine":
+        frac = min(max((step - opt["warmup_steps"])
+                       / max(opt["total_steps"] - opt["warmup_steps"], 1),
+                       0.0), 1.0)
+        decay = 0.1 + 0.45 * (1 + math.cos(math.pi * frac))
+    else:
+        raise ValueError(f"unknown schedule {opt['schedule']!r}")
+    return opt["learning_rate"] * warm * decay
+
+
+def served_gaps(cfg: Dict[str, Any], seed: int, weight_dtype: str,
+                seqs: Sequence[Tuple[np.ndarray, np.ndarray]], *,
+                control: bool = False, rows: int = 4) -> List[float]:
+    """For each (prompt, served tokens): the widest gap by which a served
+    token's logit lies below the reference's best at its position.
+
+    ``control``: instead of the served token, the token that the float8
+    reference puts first at each of those positions.
+    """
+    params = jax.jit(partial(init, cfg))(jax.random.key(seed))
+    if weight_dtype != "float32":
+        params = jax.jit(lambda t: jax.tree.map(
+            lambda x: x.astype(weight_dtype).astype(jnp.float32), t))(params)
+    S = cfg["n_positions"]
+
+    @jax.jit
+    def gaps(params, tokens, targets, valid):
+        ref = logits(cfg, params, tokens)
+        best = jnp.max(ref, axis=-1)
+        if control:
+            targets = jnp.argmax(logits(cfg, params, tokens, "float8"), -1)
+        got = jnp.take_along_axis(ref, targets[..., None], axis=-1)[..., 0]
+        return jnp.max(jnp.where(valid, best - got, -jnp.inf), axis=-1)
+
+    out = []
+    for i in range(0, len(seqs), rows):
+        block = seqs[i:i + rows]
+        tokens = np.zeros((rows, S), np.int32)
+        targets = np.zeros((rows, S), np.int32)
+        valid = np.zeros((rows, S), bool)
+        for r, (prompt, served) in enumerate(block):
+            seq = np.concatenate([prompt, served])
+            n, P = len(seq) - 1, len(prompt)
+            if n > S:
+                raise ValueError(f"sequence of {n} tokens > context {S}")
+            tokens[r, :n] = seq[:-1]
+            targets[r, :n] = seq[1:]
+            valid[r, P - 1:n] = True      # the positions that served
+        out.extend(float(g) for g in
+                   np.asarray(gaps(params, tokens, targets, valid))[
+                       :len(block)])
+    return out

@@ -1,0 +1,127 @@
+"""Runs a benchmark cell on the CPU at a tiny size, whole or with a fault
+planted in the timed path, for the tests beside this file.
+
+    JAX_PLATFORMS=cpu python tests/chipbench/rehearse.py \\
+        gpt2m-train-1chip [fault]
+
+prints the run's result line.  The cell keeps its traffic mix, plan,
+window and checks; only the widths, the batch and the slots shrink, and
+the harness's look for a TPU is skipped.
+"""
+from __future__ import annotations
+
+import contextlib
+import dataclasses
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+BENCH = os.path.join(ROOT, "benchmarks", "chip")
+for p in (BENCH, os.path.join(ROOT, "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
+TINY = dict(n_layer=2, n_embd=64, n_head=4, n_inner=256, vocab_size=512,
+            n_positions=64)
+# cells rehearsed here before BENCHMARK.json holds them
+ENTRIES = {
+    "gpt2m-serve-batch": {"name": "gpt2m-serve-batch", "config": "gpt2m",
+                          "traffic": "serve_backlog_48", "chips": 1},
+}
+
+
+def tiny_cell(workload: str, root: str = ROOT):
+    import numpy as np
+
+    from chipbench import spec
+    cell = spec.load_cell(workload, root, ENTRIES.get(workload))
+    traffic = dict(cell.traffic)
+    if traffic["kind"] == "train":
+        traffic.update(batch=4, seq=32, reference_rows=2,
+                       documents=dict(traffic["documents"], eos_id=511,
+                                      median_tokens=10))
+    else:
+        traffic.update(slots=4, max_len=64, requests_per_wave=10,
+                       check_requests=10,
+                       prompt=dict(median=12, sigma=0.5, min=4, max=40),
+                       output=dict(median=6, sigma=0.5, min=2, max=16))
+    return dataclasses.replace(
+        cell, config=dict(cell.config, **TINY), traffic=traffic,
+        chips=int(np.prod(traffic["mesh"])))
+
+
+@contextlib.contextmanager
+def planted(fault: str):
+    """A fault in the timed path: the program's step or exchange as the
+    timed path calls it, broken underneath the harness."""
+    import jax
+
+    import repro.serve.engine as engine
+    import repro.train.loop as loop
+    saved = []
+
+    def patch(obj, name, value):
+        saved.append((obj, name, getattr(obj, name)))
+        setattr(obj, name, value)
+
+    build = loop.build_train_step
+    if fault == "state_unchanged":
+        def broken_build(*a, **k):
+            step, sh = build(*a, **k)
+
+            def step_keeps_state(params, opt_state, batch):
+                _, _, metrics = step(params, opt_state, batch)
+                return params, opt_state, metrics
+            return jax.jit(step_keeps_state), sh
+        patch(loop, "build_train_step", broken_build)
+    elif fault == "half_batch":
+        def broken_build(model, plan, mesh, tcfg, *, batch_shapes, **k):
+            half = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+                (s.shape[0] // 2,) + s.shape[1:], s.dtype), batch_shapes)
+            step, sh = build(model, plan, mesh, tcfg, batch_shapes=half,
+                             **k)
+
+            def step_on_half(params, opt_state, batch):
+                return step(params, opt_state, jax.tree.map(
+                    lambda x: x[:x.shape[0] // 2], batch))
+            return jax.jit(step_on_half), sh
+        patch(loop, "build_train_step", broken_build)
+    elif fault == "token_altered":
+        build_decode = engine.build_decode_slots_step
+
+        def broken_decode(model, *a, **k):
+            step, sh = build_decode(model, *a, **k)
+            vocab = model.cfg.vocab_size
+
+            def altered(params, cache, tokens, live):
+                logits, nxt, cache = step(params, cache, tokens, live)
+                return logits, nxt.at[0, 0].set((nxt[0, 0] + 1) % vocab), \
+                    cache
+            return altered, sh
+        patch(engine, "build_decode_slots_step", broken_decode)
+    elif fault:
+        raise ValueError(f"unknown fault {fault!r}")
+    try:
+        yield
+    finally:
+        for obj, name, value in reversed(saved):
+            setattr(obj, name, value)
+
+
+def rehearse(workload: str, fault: str = "", seed: int = 2 ** 31 + 7,
+             seconds: float = 0.0) -> dict:
+    """The result line of one run.  A window of 0 s holds one training
+    step or one serving wave, all of whose requests are checked."""
+    sys.path.insert(0, BENCH)
+    import run
+    cell = tiny_cell(workload)
+    with planted(fault):
+        return run.measure(cell, seed, seconds, False, time.perf_counter(),
+                           require_tpu=False)
+
+
+if __name__ == "__main__":
+    print(json.dumps(rehearse(*sys.argv[1:3])))
