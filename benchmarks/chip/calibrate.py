@@ -13,8 +13,9 @@ One JSON line per seed and reading on standard output:
               float8 (the upper reading is the smallest of these);
               a serving cell reads it on the sequences the program
               served, so it needs --program too;
-  --faults    training: the reference with half of each batch left
-              out.
+  --faults    training: the reference with each fault of
+              ``gpt2_ref.FAULTS`` planted in its steps, or those named
+              (``--faults half_batch``).
 
 Needs the chips the cell asks for, like ``run.py``.
 """
@@ -23,6 +24,8 @@ import json
 import os
 import sys
 import time
+
+import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
@@ -34,6 +37,16 @@ from chipbench import check, gen, gpt2_ref, spec  # noqa: E402
 
 def emit(**fields) -> None:
     print(json.dumps(fields), flush=True)
+
+
+def worst_leaves(prog, ref, n=3):
+    """The leaves that set ``grad_gap`` and ``change_gap``, worst first."""
+    tiny = check.ROUND_OFF_GRAD * float(np.median(list(ref["grad"].values())))
+    keep = {"grad": None, "change": lambda leaf: ref["grad"][leaf] >= tiny}
+    return {number: sorted(check.leaf_gaps(prog[number], ref[number],
+                                           keep[number]).items(),
+                           key=lambda kv: -kv[1])[:n]
+            for number in ("grad", "change")}
 
 
 def train_seed(cell, seed, args):
@@ -50,14 +63,17 @@ def train_seed(cell, seed, args):
                              require_tpu=args.require_tpu)
         ref = out["reference"]
         emit(seed=seed, kind="program", readings=out["readings"],
-             setup_s=out["setup_s"], losses=ref["losses"])
+             setup_s=out["setup_s"], losses=ref["losses"],
+             leaves=worst_leaves(out["program"], ref))
     ref = ref or steps()
     if args.control:
+        control = steps(precision="float8")
         emit(seed=seed, kind="control",
-             readings=check.train_readings(steps(precision="float8"), ref))
-    if args.faults:
-        emit(seed=seed, kind="half_batch",
-             readings=check.train_readings(steps(keep_rows=0.5), ref))
+             readings=check.train_readings(control, ref),
+             leaves=worst_leaves(control, ref))
+    for fault in args.faults:
+        emit(seed=seed, kind=fault,
+             readings=check.train_readings(steps(fault=fault), ref))
 
 
 def serve_seed(cell, seed, args):
@@ -80,8 +96,12 @@ def main(argv=None) -> int:
                     help="comma-separated run seeds")
     ap.add_argument("--program", action="store_true")
     ap.add_argument("--control", action="store_true")
-    ap.add_argument("--faults", action="store_true")
+    ap.add_argument("--faults", nargs="?", const="all", default="",
+                    help="comma-separated faults of gpt2_ref.FAULTS; "
+                    "all of them if none is named")
     args = ap.parse_args(argv)
+    args.faults = (gpt2_ref.FAULTS[1:] if args.faults == "all"
+                   else [f for f in args.faults.split(",") if f])
     args.require_tpu = True
     cell = spec.load_cell(args.workload)
     from repro.launch import enable_compile_cache

@@ -198,5 +198,5 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t0: float, *,
         [batches.batch_at(i) for i in range(clock.n_check)],
         rows=traffic["reference_rows"])
     out["readings"] = check.train_readings(prog, ref)
-    out["reference"] = ref
+    out["program"], out["reference"] = prog, ref
     return out

@@ -15,8 +15,9 @@ through.
 """
 from __future__ import annotations
 
+import json
 import math
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Dict, List, Sequence, Tuple
 
 import jax
@@ -214,44 +215,85 @@ def adamw_step(opt: Dict[str, Any]):
     return adamw
 
 
+_zeros = jax.jit(lambda t: jax.tree.map(jnp.zeros_like, t))
+_copy = jax.jit(lambda t: jax.tree.map(jnp.copy, t))
+_negate = jax.jit(lambda new, old: jax.tree.map(lambda a, b: 2 * b - a,
+                                                new, old))
+
+
+@lru_cache(maxsize=None)
+def _step_programs(cfg_json: str, opt_json: str, z_loss: float,
+                   precision: str):
+    """The jitted init, gradient and AdamW programs of one configuration,
+    kept for the process: ``calibrate.py`` runs many seeds and variants
+    through them and compiles each once."""
+    cfg = json.loads(cfg_json)
+    return (jax.jit(partial(init, cfg)),
+            grad_accumulator(cfg, z_loss, precision),
+            adamw_step(json.loads(opt_json)))
+
+
+FAULTS = ("", "half_batch", "state_unchanged", "moments_not_carried",
+          "update_negated")
+
+
 def train_steps(cfg: Dict[str, Any], opt: Dict[str, Any], z_loss: float,
                 seed: int, batches: Sequence[Dict[str, np.ndarray]], *,
                 precision: str = "float32", rows: int = 1,
-                keep_rows: float = 1.0) -> Dict[str, Any]:
+                fault: str = "") -> Dict[str, Any]:
     """AdamW steps over ``batches`` from the seed's weights: each step's
     loss, every leaf's norm of the first (clipped) gradient, and every
     leaf's norm of the parameters' change over all the steps.
 
     The gradient of a step is summed over blocks of ``rows`` rows, so the
-    reference fits beside nothing else on one chip.  ``keep_rows`` < 1
-    plants a fault: only that share of each batch's rows counts, the mean
-    taken over those.
+    reference fits beside nothing else on one chip.  ``fault`` plants one
+    in the steps, to read what it does to the numbers compared:
+    ``half_batch`` counts half of each batch's rows, the mean taken over
+    those; ``state_unchanged`` returns the parameters and moments it was
+    given; ``moments_not_carried`` starts every step from zero moments at
+    step count 1; ``update_negated`` applies each step's update with its
+    sign turned.
     """
-    params = jax.jit(partial(init, cfg))(jax.random.key(seed))
+    if fault not in FAULTS:
+        raise ValueError(f"unknown fault {fault!r}")
+    init_fn, accumulate, adamw = _step_programs(
+        json.dumps(cfg, sort_keys=True), json.dumps(opt, sort_keys=True),
+        z_loss, precision)
+    params = init_fn(jax.random.key(seed))
     p0 = jax.device_get(params)
-    zeros = jax.jit(lambda t: jax.tree.map(jnp.zeros_like, t))
-    accumulate = grad_accumulator(cfg, z_loss, precision)
-    adamw = adamw_step(opt)
-    m, v = zeros(params), zeros(params)
+    m, v = _zeros(params), _zeros(params)
     losses, grad0 = [], None
     for t, batch in enumerate(batches):
         tokens = np.asarray(batch["tokens"])
         labels = np.asarray(batch["labels"])
-        keep = max(1, int(round(len(tokens) * keep_rows)))
+        keep = len(tokens) // 2 if fault == "half_batch" else len(tokens)
         tokens, labels = tokens[:keep], labels[:keep]
         n_tokens = int(np.sum(labels[:, 1:] >= 0))
-        acc = (zeros(params), jnp.float32(0), jnp.float32(0))
+        acc = (_zeros(params), jnp.float32(0), jnp.float32(0))
         for r in range(0, keep, rows):
             acc = accumulate(acc, params, tokens[r:r + rows],
                              labels[r:r + rows])
         grads, nll, zsq = acc
         losses.append((float(nll) + z_loss * float(zsq)) / n_tokens)
-        params, grads, m, v = adamw(
-            params, grads, m, v, jnp.float32(t + 1),
-            jnp.float32(lr_at(opt, t)), jnp.float32(n_tokens))
+        if fault == "moments_not_carried":
+            m, v, count = _zeros(params), _zeros(params), 1
+        else:
+            count = t + 1
+        keep_old = fault in ("state_unchanged", "update_negated")
+        new, grads, new_m, new_v = adamw(
+            _copy(params) if keep_old else params, grads, m, v,
+            jnp.float32(count), jnp.float32(lr_at(opt, t)),
+            jnp.float32(n_tokens))
         if t == 0:
             grad0 = leaf_norms(grads)
         del grads
+        if fault == "state_unchanged":
+            m, v = _zeros(params), _zeros(params)
+        elif fault == "update_negated":
+            params, m, v = _negate(new, params), new_m, new_v
+        else:
+            params, m, v = new, new_m, new_v
+        del new, new_m, new_v
     p_end = jax.device_get(params)
     del params, m, v
     return {"losses": losses, "grad": grad0,

@@ -68,14 +68,23 @@ def planted(fault: str):
         setattr(obj, name, value)
 
     build = loop.build_train_step
-    if fault == "state_unchanged":
+    # what a broken step hands on, from the state it was given (p, o) and
+    # the state the program's step made (new_p, new_o)
+    hands_on = {
+        "state_unchanged": lambda p, o, new_p, new_o: (p, o),
+        "moments_not_carried": lambda p, o, new_p, new_o: (new_p, o),
+        "update_negated": lambda p, o, new_p, new_o: (
+            jax.tree.map(lambda a, b: 2 * b - a, new_p, p), new_o),
+    }
+    if fault in hands_on:
         def broken_build(*a, **k):
             step, sh = build(*a, **k)
 
-            def step_keeps_state(params, opt_state, batch):
-                _, _, metrics = step(params, opt_state, batch)
-                return params, opt_state, metrics
-            return jax.jit(step_keeps_state), sh
+            def broken_step(params, opt_state, batch):
+                new_p, new_o, metrics = step(params, opt_state, batch)
+                return (*hands_on[fault](params, opt_state, new_p, new_o),
+                        metrics)
+            return jax.jit(broken_step), sh
         patch(loop, "build_train_step", broken_build)
     elif fault == "half_batch":
         def broken_build(model, plan, mesh, tcfg, *, batch_shapes, **k):

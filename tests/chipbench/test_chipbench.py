@@ -298,6 +298,65 @@ def test_training_rows_differ_and_are_seeded():
 
 
 # ------------------------------------------------------------------ #
+# the numbers that decide correct
+# ------------------------------------------------------------------ #
+
+# three checked steps; the key bias's gradient is zero under softmax, so
+# Adam moves it by round-off alone
+REF = {"losses": [11.0, 12.5, 13.5],
+       "grad": {"w": 1.0, "b": 0.5, "bk": 1e-6},
+       "change": {"w": 0.3, "b": 0.2, "bk": 0.1}}
+
+
+def test_a_program_that_matches_the_reference_reads_zero():
+    readings = check.train_readings(REF, REF)
+    assert readings == {"loss_gap": 0.0, "grad_gap": 0.0, "change_gap": 0.0,
+                        "grad_gap.median": 0.0, "change_gap.median": 0.0,
+                        "loss_gap.0": 0.0, "loss_gap.1": 0.0,
+                        "loss_gap.2": 0.0}
+    assert check.passed(check.verdict(readings, {"loss_gap": 0.0}))
+
+
+def test_the_loss_gap_is_the_widest_checked_step():
+    prog = dict(REF, losses=[11.001, 12.53, 13.49])
+    readings = check.train_readings(prog, REF)
+    assert [readings[f"loss_gap.{k}"] for k in range(3)] == \
+        pytest.approx([0.001, 0.03, 0.01])
+    assert readings["loss_gap"] == pytest.approx(0.03)
+
+
+def test_a_norm_gap_is_over_the_larger_of_the_leaf_and_the_median_leaf():
+    # median reference gradient 0.5: a gap of 0.01 reads 0.01 on the leaf
+    # of norm 1, and 0.02 on the all but zero one
+    prog = dict(REF, grad={"w": 1.01, "b": 0.5, "bk": 1e-6})
+    assert check.train_readings(prog, REF)["grad_gap"] == pytest.approx(0.01)
+    prog = dict(REF, grad={"w": 1.0, "b": 0.5, "bk": 0.01})
+    assert check.train_readings(prog, REF)["grad_gap"] == \
+        pytest.approx(0.02, rel=1e-3)
+
+
+def test_a_leaf_moved_by_round_off_alone_is_left_out_of_the_change():
+    prog = dict(REF, change={"w": 0.3, "b": 0.2, "bk": 0.5})
+    assert check.train_readings(prog, REF)["change_gap"] == 0.0
+    # the median is over the leaves kept: 0.25
+    prog = dict(REF, change={"w": 0.3, "b": 0.1, "bk": 0.1})
+    assert check.train_readings(prog, REF)["change_gap"] == \
+        pytest.approx(0.4)
+
+
+@pytest.mark.parametrize("number,prog", [
+    ("loss_gap", dict(REF, losses=[11.0, 12.5])),
+    ("grad_gap", dict(REF, grad={"w": 1.0, "b": 0.5})),
+    ("change_gap", dict(REF, change={"w": 0.3, "b": 0.2, "other": 0.1})),
+])
+def test_a_missing_step_or_leaf_fails(number, prog):
+    checks = check.verdict(check.train_readings(prog, REF),
+                           {number: 1e9})
+    assert checks[number]["value"] is None
+    assert not check.passed(checks)
+
+
+# ------------------------------------------------------------------ #
 # rehearsals: each cell at a tiny size, whole, under its control, and
 # with each fault its timed path can have
 # ------------------------------------------------------------------ #
@@ -316,6 +375,8 @@ def _sound(line):
     ("gpt2m-train-1chip", ""),
     ("gpt2m-train-1chip", "state_unchanged"),
     ("gpt2m-train-1chip", "half_batch"),
+    ("gpt2m-train-1chip", "moments_not_carried"),
+    ("gpt2m-train-1chip", "update_negated"),
     ("gpt2m-serve-batch", ""),
     ("gpt2m-serve-batch", "token_altered"),
 ])
@@ -331,24 +392,51 @@ def test_rehearsal_on_one_device(workload, fault):
         m["name"] for m in rehearse.tiny_cell(workload).end_to_end}
 
 
-def test_training_control_reads_far_from_the_program():
-    """The reference computed in float8, put in the program's place, at
-    a size a test can hold: its loss gap is several times the sound
-    program's at the same size (at the cell's own size on the chip it
-    fails the cell's limits: PERF.md)."""
+def _reference_steps(seed):
+    """``steps(**kw)``: the reference's checked steps of the training
+    cell at the tiny size, as ``calibrate.py`` runs them."""
     cell = rehearse.tiny_cell("gpt2m-train-1chip")
     cfg, traffic = cell.config, cell.traffic
-    seed = 2 ** 31 + 7
     batches = gen.TrainBatches(traffic, cfg["vocab_size"], seed)
     feed = [batches.batch_at(i) for i in range(traffic["check_steps"])]
-    steps = lambda **kw: gpt2_ref.train_steps(
+    return lambda **kw: gpt2_ref.train_steps(
         cfg, traffic["optimizer"], traffic["z_loss"], seed, feed,
         rows=traffic["reference_rows"], **kw)
+
+
+def test_training_control_reads_far_from_the_program():
+    """The reference computed in float8, put in the program's place, at
+    a size a test can hold: its median leaf's gradient gap, the number
+    that fails it at the cell's own size on the chip (PERF.md), is
+    several times the sound program's at the same size, as is its loss
+    gap."""
+    seed = 2 ** 31 + 7
+    steps = _reference_steps(seed)
     ref = steps()
     control = check.train_readings(steps(precision="float8"), ref)
     program = rehearse.rehearse("gpt2m-train-1chip", seed=seed)["checks"]
+    assert control["grad_gap.median"] > \
+        3 * program["grad_gap.median"]["value"], (control, program)
     assert control["loss_gap"] > 3 * program["loss_gap"]["value"], \
         (control, program)
+
+
+@pytest.mark.parametrize("fault,number,least", [
+    ("half_batch", "grad_gap.median", 0.01),
+    ("state_unchanged", "change_gap", 0.99),
+    ("moments_not_carried", "change_gap", 0.01),
+    ("update_negated", "loss_gap", 0.1),
+])
+def test_faults_planted_in_the_reference_read_far_from_it(fault, number,
+                                                          least):
+    """What ``calibrate.py --faults`` reads: each fault planted in the
+    reference's own steps, against the sound reference.  A fault that
+    starts at step 1 leaves step 0's loss as it was."""
+    steps = _reference_steps(2 ** 31 + 11)
+    readings = check.train_readings(steps(fault=fault), steps())
+    assert readings[number] > least, readings
+    if fault != "half_batch":
+        assert readings["loss_gap.0"] == 0.0
 
 
 def test_serving_control_reads_far_from_the_program():
