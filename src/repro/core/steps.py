@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import tracing
 from repro.configs.base import ModelConfig, ShapeConfig, TrainConfig
 from repro.core.pipeline import make_pipeline_loss, pipeline_mesh
 from repro.core.plans import Plan
@@ -158,9 +159,10 @@ def build_train_step(model: Model, plan: Plan, mesh: Mesh,
             # pin grads to the ZeRO shards => XLA reduce-scatters them
             grads = jax.lax.with_sharding_constraint(
                 grads, _ns(mesh, o_specs_p))
-        lr = lr_at(opt_state.step, tcfg)
-        new_params, new_opt, stats = adamw_update(
-            grads, opt_state, params, tcfg, lr)
+        with jax.named_scope(tracing.OPTIMIZER):
+            lr = lr_at(opt_state.step, tcfg)
+            new_params, new_opt, stats = adamw_update(
+                grads, opt_state, params, tcfg, lr)
         if plan.zero_sharding:
             # updated shards all-gather back to the plan's param placement
             new_params = jax.lax.with_sharding_constraint(

@@ -13,6 +13,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.configs.base import MLAConfig, ModelConfig
 from repro.models.layers import apply_rope, dense_init
 
@@ -358,14 +359,15 @@ def attention_forward(x, params, cfg: ModelConfig, *, positions,
                       causal: bool = True, window: int = 0,
                       use_pallas: bool = False):
     """Full-sequence attention (train / prefill / encoder)."""
-    q, k, v = _qkv(x, params, cfg)
-    if cfg.rope_theta:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-    o = chunked_attention(q, k, v, causal=causal, window=window,
-                          q_positions=positions, kv_positions=positions,
-                          use_pallas=use_pallas)
-    return _out(o, params)
+    with jax.named_scope(tracing.ATTENTION):
+        q, k, v = _qkv(x, params, cfg)
+        if cfg.rope_theta:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
+        o = chunked_attention(q, k, v, causal=causal, window=window,
+                              q_positions=positions, kv_positions=positions,
+                              use_pallas=use_pallas)
+        return _out(o, params)
 
 
 def attention_prefill(x, params, cfg: ModelConfig, *, positions,
@@ -489,21 +491,23 @@ def _mla_latent(x, params, cfg: ModelConfig, positions):
 def mla_forward(x, params, cfg: ModelConfig, *, positions, window: int = 0,
                 use_pallas: bool = False):
     """Full-sequence MLA: decompress K/V per head and run chunked attention."""
-    m, dt = cfg.mla, x.dtype
-    q_nope, q_rope = _mla_q(x, params, cfg, positions)
-    c_kv, k_rope = _mla_latent(x, params, cfg, positions)
-    k_nope = jnp.einsum("bsr,hrk->bshk", c_kv, params["w_uk"].astype(dt))
-    v = jnp.einsum("bsr,hrk->bshk", c_kv, params["w_uv"].astype(dt))
-    H = cfg.n_heads
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
-                                  (*k_rope.shape[:2], H, m.rope_head_dim))],
-        axis=-1)
-    o = chunked_attention(q, k, v, causal=True, window=window,
-                          q_positions=positions, kv_positions=positions,
-                          use_pallas=use_pallas)
-    return jnp.einsum("bshk,hkd->bsd", o, params["wo"].astype(dt))
+    with jax.named_scope(tracing.ATTENTION):
+        m, dt = cfg.mla, x.dtype
+        q_nope, q_rope = _mla_q(x, params, cfg, positions)
+        c_kv, k_rope = _mla_latent(x, params, cfg, positions)
+        k_nope = jnp.einsum("bsr,hrk->bshk", c_kv, params["w_uk"].astype(dt))
+        v = jnp.einsum("bsr,hrk->bshk", c_kv, params["w_uv"].astype(dt))
+        H = cfg.n_heads
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
+                                      (*k_rope.shape[:2], H,
+                                       m.rope_head_dim))],
+            axis=-1)
+        o = chunked_attention(q, k, v, causal=True, window=window,
+                              q_positions=positions, kv_positions=positions,
+                              use_pallas=use_pallas)
+        return jnp.einsum("bshk,hkd->bsd", o, params["wo"].astype(dt))
 
 
 def mla_prefill(x, params, cfg: ModelConfig, *, positions, cache: MLACache,

@@ -13,6 +13,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
+
 # --------------------------------------------------------------------- #
 # init helpers
 # --------------------------------------------------------------------- #
@@ -114,16 +116,18 @@ def init_mlp(rng, d: int, d_ff: int, activation: str):
 
 
 def apply_mlp(x, params, activation: str):
-    if activation == "silu":
-        g = jnp.einsum("...d,df->...f", x, params["w_gate"].astype(x.dtype))
-        u = jnp.einsum("...d,df->...f", x, params["w_up"].astype(x.dtype))
-        h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
-        return jnp.einsum("...f,fd->...d", h, params["w_down"].astype(x.dtype))
-    h = jnp.einsum("...d,df->...f", x, params["w_up"].astype(x.dtype))
-    h = h + params["b_up"].astype(x.dtype)
-    h = jax.nn.gelu(h.astype(jnp.float32), approximate=True).astype(x.dtype)
-    out = jnp.einsum("...f,fd->...d", h, params["w_down"].astype(x.dtype))
-    return out + params["b_down"].astype(x.dtype)
+    with jax.named_scope(tracing.MLP):
+        dt = x.dtype
+        if activation == "silu":
+            g = jnp.einsum("...d,df->...f", x, params["w_gate"].astype(dt))
+            u = jnp.einsum("...d,df->...f", x, params["w_up"].astype(dt))
+            h = jax.nn.silu(g.astype(jnp.float32)).astype(dt) * u
+            return jnp.einsum("...f,fd->...d", h, params["w_down"].astype(dt))
+        h = jnp.einsum("...d,df->...f", x, params["w_up"].astype(dt))
+        h = h + params["b_up"].astype(dt)
+        h = jax.nn.gelu(h.astype(jnp.float32), approximate=True).astype(dt)
+        out = jnp.einsum("...f,fd->...d", h, params["w_down"].astype(dt))
+        return out + params["b_down"].astype(dt)
 
 
 # --------------------------------------------------------------------- #

@@ -13,6 +13,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.configs.base import ModelConfig
 from repro.models import blocks
 from repro.models import attention as attn_mod
@@ -144,13 +145,15 @@ class Model:
 
     def _head(self, params, x) -> jax.Array:
         cfg = self.cfg
-        x = apply_norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-        table = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-        logits = unembed(x, table, self.compute_dtype)
-        if self.logits_pspec is not None:
-            logits = jax.lax.with_sharding_constraint(
-                logits, self.logits_pspec)
-        return logits
+        with jax.named_scope(tracing.LOGITS):
+            x = apply_norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
+            table = params["embed"] if cfg.tie_embeddings \
+                else params["lm_head"]
+            logits = unembed(x, table, self.compute_dtype)
+            if self.logits_pspec is not None:
+                logits = jax.lax.with_sharding_constraint(
+                    logits, self.logits_pspec)
+            return logits
 
     def _encode(self, params, batch) -> jax.Array:
         """Whisper encoder over precomputed frame embeddings (stub frontend)."""
@@ -250,7 +253,8 @@ class Model:
     def loss(self, params, batch, *, remat: bool = True
              ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         logits, aux = self.forward(params, batch, remat=remat)
-        return lm_loss(self.cfg, logits, batch, aux)
+        with jax.named_scope(tracing.LOGITS):
+            return lm_loss(self.cfg, logits, batch, aux)
 
     # ----------------------------------------------------------------- #
     # caches

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import tracing
 from repro.configs.base import ModelConfig
 from repro.models.layers import dense_init
 
@@ -41,11 +42,12 @@ def init_moe(rng, cfg: ModelConfig):
 
 def _expert_ffn(buf, params):
     """buf: [E, C, d] -> [E, C, d] through per-expert SwiGLU."""
-    dt = buf.dtype
-    g = jnp.einsum("ecd,edf->ecf", buf, params["w_gate"].astype(dt))
-    u = jnp.einsum("ecd,edf->ecf", buf, params["w_up"].astype(dt))
-    h = jax.nn.silu(g.astype(jnp.float32)).astype(dt) * u
-    return jnp.einsum("ecf,efd->ecd", h, params["w_down"].astype(dt))
+    with jax.named_scope(tracing.MLP):
+        dt = buf.dtype
+        g = jnp.einsum("ecd,edf->ecf", buf, params["w_gate"].astype(dt))
+        u = jnp.einsum("ecd,edf->ecf", buf, params["w_up"].astype(dt))
+        h = jax.nn.silu(g.astype(jnp.float32)).astype(dt) * u
+        return jnp.einsum("ecf,efd->ecd", h, params["w_down"].astype(dt))
 
 
 def moe_forward(x, params, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:

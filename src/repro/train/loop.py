@@ -13,6 +13,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import numpy as np
 
+from repro import tracing
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.core.plans import Plan
 from repro.core.steps import build_train_step
@@ -107,6 +108,7 @@ def train(model: Model, plan: Plan, mesh, tcfg: TrainConfig, loader, *,
         step_fn = step_fn.lower(params, opt_state,
                                 jax.device_put(first, sh["batch"])).compile()
         result.compile_s = time.perf_counter() - t0
+        tracing.register("train_step", step_fn, compile_s=result.compile_s)
         mem = step_fn.memory_analysis()
         if mem is not None:
             result.step_bytes = (mem.argument_size_in_bytes
@@ -124,21 +126,25 @@ def train(model: Model, plan: Plan, mesh, tcfg: TrainConfig, loader, *,
                 except BaseException as e:
                     e.result = result        # partial losses/step times
                     raise
-            batch = jax.device_put(loader.batch_at(i), sh["batch"])
-            t0 = time.perf_counter()
-            params, opt_state, metrics = step_fn(params, opt_state, batch)
-            loss = float(metrics["loss"])         # blocks on completion
-            dt = time.perf_counter() - t0
-            result.losses.append(loss)
-            result.step_times.append(dt)
-            if log_every and (i % log_every == 0 or i == steps - 1):
-                log_fn(f"step {i:5d} loss {loss:8.4f} "
-                       f"ce {float(metrics['ce']):8.4f} "
-                       f"gnorm {float(metrics['grad_norm']):7.3f} "
-                       f"{dt * 1e3:8.1f} ms "
-                       f"{flops / max(dt, 1e-9) / 1e12:6.2f} TFLOP/s")
-            if ckpt_dir and ckpt_every and (i + 1) % ckpt_every == 0:
-                save_checkpoint(ckpt_dir, i + 1, params, opt_state)
+            with jax.profiler.StepTraceAnnotation("train", step_num=i):
+                with tracing.span("train.batch"):
+                    batch = jax.device_put(loader.batch_at(i), sh["batch"])
+                t0 = time.perf_counter()
+                params, opt_state, metrics = step_fn(params, opt_state,
+                                                     batch)
+                with tracing.span("train.sync"):
+                    loss = float(metrics["loss"])  # blocks on completion
+                dt = time.perf_counter() - t0
+                result.losses.append(loss)
+                result.step_times.append(dt)
+                if log_every and (i % log_every == 0 or i == steps - 1):
+                    log_fn(f"step {i:5d} loss {loss:8.4f} "
+                           f"ce {float(metrics['ce']):8.4f} "
+                           f"gnorm {float(metrics['grad_norm']):7.3f} "
+                           f"{dt * 1e3:8.1f} ms "
+                           f"{flops / max(dt, 1e-9) / 1e12:6.2f} TFLOP/s")
+                if ckpt_dir and ckpt_every and (i + 1) % ckpt_every == 0:
+                    save_checkpoint(ckpt_dir, i + 1, params, opt_state)
         result.metrics_last = {k: float(v) for k, v in metrics.items()}
         if ckpt_dir:
             save_checkpoint(ckpt_dir, steps, params, opt_state)
