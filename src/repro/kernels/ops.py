@@ -8,10 +8,14 @@ makes this choice.
 """
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas.ops.tpu import flash_attention as _tpu_fa
 
 from repro.kernels import mamba_scan as _scan
 from repro.kernels import flash_attention as _fa
@@ -31,16 +35,141 @@ def _pad_axis(x, mult: int, axis: int):
     return jnp.pad(x, widths)
 
 
+# ------------------------------------------------------------------ #
+# fused causal flash attention with a backward pass: jax's Pallas TPU
+# flash attention kernels (jax.experimental.pallas.ops.tpu), forward,
+# dk/dv and dq, with bf16 operands, fp32 softmax statistics and
+# accumulation, scores in VMEM, and blocks wholly above the diagonal
+# skipped.  Block sizes are from a sweep on a TPU v5e at S 1024, D 64.
+# ------------------------------------------------------------------ #
+
+def fused_block(seq: int) -> int:
+    """The block along q and k of the forward and the dq kernel."""
+    return min(seq, 512)
+
+
+def _dkv_block(seq: int) -> int:
+    """The block of the dk/dv kernel: the whole sequence up to 1024."""
+    return seq if seq <= 1024 else fused_block(seq)
+
+
+def fused_attention_takes(q_shape, k_shape, v_shape, *, causal: bool,
+                          window: int) -> bool:
+    """Whether the fused kernel computes attention of these shapes
+    (model layout, q: [B, Sq, H, Dk]; k/v: [B, Sk, KV, D*]): plain
+    causal self-attention, one kv head per query head, Dk == Dv, and a
+    sequence its block divides into lane-aligned tiles."""
+    _, Sq, H, Dk = q_shape
+    _, Sk, KV, _ = k_shape
+    Dv = v_shape[-1]
+    blk = fused_block(Sq)
+    return (causal and not window and H == KV and Dk == Dv and Sq == Sk
+            and (Dk <= 128 or Dk % 128 == 0)
+            and blk % 128 == 0 and Sq % blk == 0)
+
+
+def _interpreting(interpret: bool):
+    return pltpu.force_tpu_interpret_mode() if interpret \
+        else contextlib.nullcontext()
+
+
+def _fused_forward(q, k, v, save_residuals: bool):
+    """o, or with ``save_residuals`` (o, l, m): the softmax's row sums
+    and maxima, [B, H, S] fp32."""
+    b = fused_block(q.shape[2])
+    return _tpu_fa._flash_attention_impl(
+        q, k, v, None, None, save_residuals, True, q.shape[3] ** -0.5,
+        1, b, b, b, False)
+
+
+def _fused_dq(q, k, v, l, m, do, di):
+    """jax's dq kernel over blocks of ``fused_block``, fed dO.O and the
+    softmax statistics at the one lane width it reads of them.  jax's own
+    wrapper broadcasts dO.O across the whole k block in HBM, four times
+    the bytes at a block of 512, and the compiler then moves an operand
+    of the MLP's weight gradient out of VMEM."""
+    B, H, S, D = q.shape
+    b, lanes = fused_block(S), _tpu_fa.MIN_BLOCK_SIZE
+    l, m, di = (jnp.broadcast_to(x[..., None], (*x.shape, lanes))
+                for x in (l, m, di))
+
+    def q_map(bi, hi, qi, ki):
+        return bi, hi, qi, 0
+
+    def kv_map(bi, hi, qi, ki):
+        # a block above the diagonal is skipped: fetch block 0 instead
+        return bi, hi, jax.lax.select(
+            _tpu_fa.below_or_on_diag(qi, b, ki, b), ki, 0), 0
+
+    q_spec = pl.BlockSpec((1, 1, b, D), q_map)
+    kv_spec = pl.BlockSpec((1, 1, b, D), kv_map)
+    stat_spec = pl.BlockSpec((1, 1, b, lanes), q_map)
+    kernel = partial(_tpu_fa._flash_attention_dq_kernel, sm_scale=D ** -0.5,
+                     causal=True, mask_value=_tpu_fa.DEFAULT_MASK_VALUE,
+                     block_k=b, kv_seq_len=S)
+    with jax.named_scope("flash_mha_bwd_dq"):
+        dq, _ = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=0, grid=(B, H, S // b, S // b),
+                in_specs=[q_spec, kv_spec, kv_spec, None, None, None,
+                          stat_spec, stat_spec, q_spec, stat_spec],
+                out_specs=[q_spec, None],
+                scratch_shapes=[pltpu.VMEM((b, D), jnp.float32)]),
+            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), None],
+            compiler_params=pltpu.CompilerParams(dimension_semantics=(
+                "parallel", "parallel", "parallel", "arbitrary")),
+        )(q, k, v, None, None, None, l, m, do, di)
+    return dq
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _fused_causal(q, k, v, interpret: bool):
+    """[B, H, S, D] causal attention through jax's fused flash kernel."""
+    with _interpreting(interpret):
+        return _fused_forward(q, k, v, False)
+
+
+def _fused_causal_fwd(q, k, v, interpret):
+    with _interpreting(interpret):
+        o, l, m = _fused_forward(q, k, v, True)
+    return o, (q, k, v, o, l, m)
+
+
+def _fused_causal_bwd(interpret, res, do):
+    q, k, v, o, l, m = res
+    b = _dkv_block(q.shape[2])
+    di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    with _interpreting(interpret):
+        dk, dv = _tpu_fa._flash_attention_bwd_dkv(
+            q, k, v, None, None, l, m, do, di, block_q_major=b, block_q=b,
+            block_k_major=b, block_k=b, sm_scale=q.shape[3] ** -0.5,
+            causal=True, mask_value=_tpu_fa.DEFAULT_MASK_VALUE)
+        return _fused_dq(q, k, v, l, m, do, di), dk, dv
+
+
+_fused_causal.defvjp(_fused_causal_fwd, _fused_causal_bwd)
+
+
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k",
                                    "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
                     interpret: bool | None = None):
     """Model-layout flash attention.  q: [B, Sq, H, Dk]; k/v: [B, Sk, KV, D*].
-    Pads seq to block multiples and head_dim to a lane multiple (128),
-    runs the kernel in [B, H, S, D] layout, unpads."""
+
+    Where ``fused_attention_takes`` the shapes, the fused causal kernels
+    run, and the call is differentiable.  Otherwise the forward-only kernel of
+    kernels/flash_attention.py runs over ``block_q`` x ``block_k`` tiles:
+    seq padded to block multiples and head_dim to a lane multiple (128),
+    in [B, H, S, D] layout, unpadded after."""
     if interpret is None:
         interpret = _default_interpret()
+    if fused_attention_takes(q.shape, k.shape, v.shape, causal=causal,
+                             window=window):
+        o = _fused_causal(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                          v.transpose(0, 2, 1, 3), interpret)
+        return o.transpose(0, 2, 1, 3)
     B, Sq, H, Dk = q.shape
     Sk, Dv = k.shape[1], v.shape[-1]
     qT = _pad_axis(_pad_axis(q.transpose(0, 2, 1, 3), block_q, 2), 128, 3)

@@ -2,9 +2,12 @@
 KV caches, and Multi-head Latent Attention (MLA) with an absorbed-matmul
 latent-cache decode path.
 
-The chunked implementation is the memory-bounded pure-jnp path (and the
-oracle for kernels/flash_attention.py); on TPU the Pallas kernel can be
-swapped in via ``use_pallas`` in the model call.
+The chunked implementation is the memory-bounded pure-jnp path and the
+oracle for the Pallas kernels.  On a TPU, plain causal self-attention
+whose shapes the fused kernel takes (``kernels.ops.fused_attention_takes``)
+runs through that kernel, forward and backward, wherever the program is
+built for one device; ``use_pallas`` in the model call swaps in
+kernels/flash_attention.py for the rest.
 """
 from __future__ import annotations
 
@@ -12,6 +15,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro import tracing
 from repro.configs.base import MLAConfig, ModelConfig
@@ -34,6 +38,30 @@ def _pad_to_multiple(x, mult: int, axis: int):
     return jnp.pad(x, widths), size
 
 
+def _one_device_program() -> bool:
+    """Whether the program being traced runs on one device, or this is
+    the per-device body of a ``shard_map``.  Under GSPMD's partitioning
+    a kernel's custom call would gather its operands."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return jax.device_count() == 1
+    return all(n == 1 or t == AxisType.Manual
+               for n, t in zip(mesh.axis_sizes, mesh.axis_types))
+
+
+def _fused_applies(q, k, v, *, causal: bool, window: int, q_positions,
+                   kv_positions) -> bool:
+    """Whether the fused kernel computes this call: on a TPU, for shapes
+    it takes, with the plain causal mask (no positions of the caller's
+    own), in a program built for one device."""
+    from repro.kernels import ops as kernel_ops
+    return (q_positions is None and kv_positions is None
+            and jax.default_backend() == "tpu"
+            and kernel_ops.fused_attention_takes(
+                q.shape, k.shape, v.shape, causal=causal, window=window)
+            and _one_device_program())
+
+
 def chunked_attention(
     q, k, v, *,
     causal: bool = True,
@@ -47,10 +75,16 @@ def chunked_attention(
     """Memory-bounded attention.
 
     q: [B, Sq, H, Dk]; k: [B, Sk, KV, Dk]; v: [B, Sk, KV, Dv]; H % KV == 0.
-    Softmax accumulates in fp32 with the online max/denominator recurrence.
-    Returns [B, Sq, H, Dv].
+    Positions of None are the plain ``arange`` of every row.  Where
+    ``_fused_applies``, the fused flash kernel computes it; otherwise
+    softmax accumulates in fp32 with the online max/denominator
+    recurrence over q and kv chunks.  Returns [B, Sq, H, Dv].
     """
-    if use_pallas:
+    fused = _fused_applies(q, k, v, causal=causal, window=window,
+                           q_positions=q_positions, kv_positions=kv_positions)
+    tracing.note(tracing.FUSED_ATTENTION if fused
+                 else tracing.CHUNKED_ATTENTION)
+    if fused or use_pallas:
         from repro.kernels import ops as kernel_ops
         return kernel_ops.flash_attention(
             q, k, v, causal=causal, window=window)
@@ -355,15 +389,24 @@ def _out(o, params):
     return y
 
 
+def _plain_positions(positions, x):
+    """``positions``, or the plain [1, S] ``arange`` where it is None."""
+    if positions is None:
+        return jnp.arange(x.shape[1], dtype=jnp.int32)[None]
+    return positions
+
+
 def attention_forward(x, params, cfg: ModelConfig, *, positions,
                       causal: bool = True, window: int = 0,
                       use_pallas: bool = False):
-    """Full-sequence attention (train / prefill / encoder)."""
+    """Full-sequence attention (train / prefill / encoder).  ``positions``
+    None: every row is at the plain ``arange``, so the mask is plain."""
     with jax.named_scope(tracing.ATTENTION):
         q, k, v = _qkv(x, params, cfg)
         if cfg.rope_theta:
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k = apply_rope(k, positions, cfg.rope_theta)
+            pos = _plain_positions(positions, x)
+            q = apply_rope(q, pos, cfg.rope_theta)
+            k = apply_rope(k, pos, cfg.rope_theta)
         o = chunked_attention(q, k, v, causal=causal, window=window,
                               q_positions=positions, kv_positions=positions,
                               use_pallas=use_pallas)
@@ -493,8 +536,9 @@ def mla_forward(x, params, cfg: ModelConfig, *, positions, window: int = 0,
     """Full-sequence MLA: decompress K/V per head and run chunked attention."""
     with jax.named_scope(tracing.ATTENTION):
         m, dt = cfg.mla, x.dtype
-        q_nope, q_rope = _mla_q(x, params, cfg, positions)
-        c_kv, k_rope = _mla_latent(x, params, cfg, positions)
+        pos = _plain_positions(positions, x)
+        q_nope, q_rope = _mla_q(x, params, cfg, pos)
+        c_kv, k_rope = _mla_latent(x, params, cfg, pos)
         k_nope = jnp.einsum("bsr,hrk->bshk", c_kv, params["w_uk"].astype(dt))
         v = jnp.einsum("bsr,hrk->bshk", c_kv, params["w_uv"].astype(dt))
         H = cfg.n_heads

@@ -241,6 +241,8 @@ class Model:
         """Returns (logits [B, S, V], aux_loss)."""
         cfg = self.cfg
         x, positions, _ = self._embed_inputs(params, batch)
+        if batch.get("positions") is None:
+            positions = None        # the plain arange: a plain causal mask
         enc_out = self._encode(params, batch) if cfg.family == "encdec" else None
         x, aux = self.run_stack(params["layers"], x, positions,
                                 shared=params.get("shared"), enc_out=enc_out,

@@ -18,12 +18,22 @@ instructions to the scope it belongs to, parsed from
 compilation cache leaves debug info, and with it the scopes, out of its
 key, so a program served from an entry that unscoped code wrote holds no
 scope name: its table is then empty, never a wrong split.
+
+Notes.  ``note(event)`` counts an event of tracing, such as which path
+an attention call took (``FUSED_ATTENTION``, ``CHUNKED_ATTENTION``),
+into each ``lowering()`` open around it; a note made outside one counts
+nowhere.  Tracing runs while a program is lowered, so the caller opens
+``lowering()`` around ``lower(...)`` and hands its counts to
+``register``; ``notes(name)`` gives them back.  A program served from
+the persistent cache is still traced, so its counts are there too.
 """
 from __future__ import annotations
 
+import contextlib
 import re
+from collections import Counter
 from dataclasses import dataclass, field
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Iterator, List, Optional
 
 import jax
 
@@ -32,6 +42,9 @@ MLP = "mlp"
 LOGITS = "logits"
 OPTIMIZER = "optimizer"
 SCOPES = (ATTENTION, MLP, LOGITS, OPTIMIZER)
+
+FUSED_ATTENTION = "attention.fused"
+CHUNKED_ATTENTION = "attention.chunked"
 
 span = jax.profiler.TraceAnnotation
 
@@ -45,19 +58,47 @@ class Program:
     compiled: Any
     compile_s: float
     scopes: Optional[Dict[str, str]] = field(default=None, repr=False)
+    notes: Dict[str, int] = field(default_factory=dict)
 
 
 _PROGRAMS: Dict[str, Program] = {}
+_LOWERING: List[Counter] = []
 
 
-def register(name: str, compiled, *, compile_s: float) -> None:
-    """Keep ``compiled`` (a ``jax.stages.Compiled``) under ``name``; its
-    text is not read until ``op_scopes`` asks."""
-    _PROGRAMS[name] = Program(compiled, compile_s)
+@contextlib.contextmanager
+def lowering() -> Iterator[Counter]:
+    """The notes made inside, counted into the ``Counter`` it yields."""
+    counts: Counter = Counter()
+    _LOWERING.append(counts)
+    try:
+        yield counts
+    finally:
+        _LOWERING.remove(counts)
+
+
+def note(event: str) -> None:
+    """Count ``event`` in every ``lowering()`` open."""
+    for counts in _LOWERING:
+        counts[event] += 1
+
+
+def register(name: str, compiled, *, compile_s: float,
+             notes: Optional[Dict[str, int]] = None) -> None:
+    """Keep ``compiled`` (a ``jax.stages.Compiled``) under ``name``, with
+    the notes counted while it was lowered; its text is not read until
+    ``op_scopes`` asks."""
+    _PROGRAMS[name] = Program(compiled, compile_s, notes=dict(notes or {}))
 
 
 def registered(name: str) -> Optional[Program]:
     return _PROGRAMS.get(name)
+
+
+def notes(name: str) -> Dict[str, int]:
+    """The notes counted while the program registered as ``name`` was
+    lowered: empty when nothing is registered or nothing was noted."""
+    prog = _PROGRAMS.get(name)
+    return dict(prog.notes) if prog else {}
 
 
 def scope_of(op_name: str) -> Optional[str]:

@@ -105,10 +105,13 @@ def train(model: Model, plan: Plan, mesh, tcfg: TrainConfig, loader, *,
         result = TrainResult()
         # compile ahead of the loop, so that no step time includes it
         t0 = time.perf_counter()
-        step_fn = step_fn.lower(params, opt_state,
-                                jax.device_put(first, sh["batch"])).compile()
+        with tracing.lowering() as noted:
+            step_fn = step_fn.lower(params, opt_state,
+                                    jax.device_put(first, sh["batch"]))
+        step_fn = step_fn.compile()
         result.compile_s = time.perf_counter() - t0
-        tracing.register("train_step", step_fn, compile_s=result.compile_s)
+        tracing.register("train_step", step_fn, compile_s=result.compile_s,
+                         notes=noted)
         mem = step_fn.memory_analysis()
         if mem is not None:
             result.step_bytes = (mem.argument_size_in_bytes

@@ -70,6 +70,126 @@ def test_flash_attention_property(s, h, d, seed):
 
 
 # ------------------------------------------------------------------ #
+# fused causal flash attention with its backward (jax's Pallas TPU
+# kernel, behind ops.flash_attention): parity with the jnp scan, and
+# which calls take it
+# ------------------------------------------------------------------ #
+
+FUSED_CASES = [
+    # (S, dtype): one q/kv block per sequence (S <= 512), then two, of
+    # which the kernels skip the one above the diagonal
+    (256, jnp.bfloat16),
+    (256, jnp.float32),
+    (512, jnp.bfloat16),
+    (512, jnp.float32),
+    (1024, jnp.bfloat16),
+    (1024, jnp.float32),
+]
+
+
+def _rel_err(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return np.max(np.abs(got - want)) / np.max(np.abs(want))
+
+
+@pytest.mark.parametrize("S,dtype", FUSED_CASES)
+def test_fused_attention_and_its_gradients_match_the_scan(S, dtype):
+    from repro.models.attention import chunked_attention
+    rng = np.random.default_rng(S)
+    q, k, v, g = (_mk(rng, (1, S, 2, 64), dtype) for _ in range(4))
+    assert ops.fused_attention_takes(q.shape, k.shape, v.shape,
+                                     causal=True, window=0)
+
+    def fused(q, k, v):
+        return ops.flash_attention(q, k, v, interpret=True)
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)
+                                       * g.astype(jnp.float32))
+
+    got = [fused(q, k, v)] + list(
+        jax.grad(loss(fused), argnums=(0, 1, 2))(q, k, v))
+    want = [chunked_attention(q, k, v)] + list(
+        jax.grad(loss(chunked_attention), argnums=(0, 1, 2))(q, k, v))
+    # bf16: q/k/v and P of the PV product round to 8 bits; f32: exact
+    # but for the order of the sums
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.dtype == b.dtype, name
+        assert _rel_err(a, b) < tol, (name, _rel_err(a, b))
+
+
+def _dispatch_call(case):
+    """Arguments of one ``chunked_attention`` call: the plain causal
+    self-attention of 2 heads of 64 over 256 positions, changed as
+    ``case`` says."""
+    S, H, KV, Dk, Dv = 256, 2, 2, 64, 64
+    kw = dict(causal=True, window=0, q_positions=None, kv_positions=None)
+    if case == "window":
+        kw["window"] = 128
+    elif case == "gqa":
+        KV = 1
+    elif case == "dk_ne_dv":
+        Dv = 32
+    elif case == "not_causal":
+        kw["causal"] = False
+    elif case == "s_not_divided":
+        S = 640               # blocks of 512 leave a remainder of 128
+    elif case == "positions":
+        kw["q_positions"] = kw["kv_positions"] = jnp.arange(S)[None] + 3
+    q = jnp.zeros((1, S, H, Dk), jnp.bfloat16)
+    k = jnp.zeros((1, S, KV, Dk), jnp.bfloat16)
+    v = jnp.zeros((1, S, KV, Dv), jnp.bfloat16)
+    return (q, k, v), kw
+
+
+@pytest.mark.parametrize("case,takes", [
+    ("plain", True), ("window", False), ("gqa", False), ("dk_ne_dv", False),
+    ("not_causal", False), ("s_not_divided", False), ("positions", False),
+    ("cpu", False), ("mesh", False), ("manual_mesh", True)])
+def test_which_attention_calls_take_the_fused_kernel(monkeypatch, case,
+                                                     takes):
+    from jax.sharding import AbstractMesh, AxisType
+
+    from repro import tracing
+    from repro.models import attention
+    if case != "cpu":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args, kw = _dispatch_call(case)
+    axis = {"mesh": AxisType.Auto, "manual_mesh": AxisType.Manual}.get(case)
+    mesh = AbstractMesh((2,), ("data",), axis_types=(axis,)) if axis \
+        else jax.sharding.get_abstract_mesh()
+    with jax.sharding.use_abstract_mesh(mesh), tracing.lowering() as noted:
+        assert attention._fused_applies(*args, **kw) == takes
+        jax.eval_shape(lambda *a: attention.chunked_attention(*a, **kw),
+                       *args)
+    assert noted == {tracing.FUSED_ATTENTION if takes
+                     else tracing.CHUNKED_ATTENTION: 1}
+
+
+@pytest.mark.parametrize("positions,path", [
+    (False, "attention.fused"), (True, "attention.chunked")])
+def test_the_model_takes_the_fused_kernel_only_with_plain_positions(
+        monkeypatch, positions, path):
+    """gpt2 on a TPU: a batch without positions of its own trains
+    through the fused kernel, forward and backward; one that brings its
+    own keeps the scan.  Traced, not run."""
+    from repro import tracing
+    from repro.configs import get_config
+    from repro.models import Model
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = Model(get_config("gpt2m").reduced())
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    tok = jnp.zeros((2, 256), jnp.int32)
+    batch = {"tokens": tok, "labels": tok}
+    if positions:
+        batch["positions"] = jnp.broadcast_to(jnp.arange(256), (2, 256))
+    with tracing.lowering() as noted:
+        jax.eval_shape(jax.grad(lambda p: model.loss(p, batch)[0]), params)
+    assert set(noted) == {path}
+
+
+# ------------------------------------------------------------------ #
 # SSD (mamba2) scan
 # ------------------------------------------------------------------ #
 

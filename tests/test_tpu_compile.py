@@ -12,6 +12,7 @@ import time: only one process may load the TPU library, and every
 test worker imports every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +20,9 @@ import numpy as np
 import pytest
 from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
+from repro import tracing
 from repro.kernels import ops
+from repro.models.attention import chunked_attention
 
 # gpt2m widths: batch 8 x context 1024, 16 heads of 64
 B, S, H, D = 8, 1024, 16, 64
@@ -68,6 +71,42 @@ def test_flash_attention_compiles(one_chip):
     _compiled_kernel_text(
         lambda q, k, v: ops.flash_attention(q, k, v, interpret=False),
         q, q, q)
+
+
+def _attention_grad(one_chip, attend):
+    """One attention call under the model's scope, and the gradient of a
+    sum of its output for q, k and v, compiled for the described chip."""
+    qkv = [_spec(one_chip, (B, S, H, D), jnp.bfloat16)] * 3
+
+    def loss(q, k, v):
+        with jax.named_scope(tracing.ATTENTION):
+            o = attend(q, k, v)
+        return jnp.sum(o.astype(jnp.float32))
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*qkv).compile()
+
+
+def test_fused_attention_gradient_compiles_with_its_backward(one_chip):
+    """The training path's attention at gpt2m widths: the fused kernel
+    runs forward and backward as custom calls, each in the attention
+    scope, in less temporary memory than the scan it replaces."""
+    fused = _attention_grad(
+        one_chip, lambda q, k, v: ops.flash_attention(q, k, v,
+                                                      interpret=False))
+    text = fused.as_text()
+    calls = re.findall(r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*tpu_custom_call'
+                       r'.*op_name="([^"]*)"', text, re.M)
+    backward = [name for name, op in calls if "transpose(" in op]
+    assert len(backward) >= 2                     # dq and dk/dv kernels
+    assert len(calls) > len(backward)             # and the forward's
+    table = tracing.scopes_of(text)
+    assert {table.get(name) for name, _ in calls} == {tracing.ATTENTION}
+
+    # here the backend is the CPU, so chunked_attention keeps its scan
+    chunked = _attention_grad(one_chip, chunked_attention)
+    assert "tpu_custom_call" not in chunked.as_text()
+    assert fused.memory_analysis().temp_size_in_bytes \
+        < chunked.memory_analysis().temp_size_in_bytes
 
 
 @pytest.mark.parametrize("batch,sq,sk,block_q", [

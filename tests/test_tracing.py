@@ -115,6 +115,34 @@ def test_the_step_hook_sees_the_state_in_its_callers_frame(run, name):
     assert all(name in f for f in run["frames"])
 
 
+def test_the_step_notes_which_attention_path_it_traced(run):
+    # on the CPU every attention call keeps the jnp scan
+    notes = run["program"].notes
+    assert set(notes) == {tracing.CHUNKED_ATTENTION}
+    assert notes[tracing.CHUNKED_ATTENTION] >= 1
+
+
+def test_the_notes_go_with_the_program_registered_after_them(monkeypatch):
+    monkeypatch.setattr(tracing, "_PROGRAMS", {})
+    tracing.note(tracing.FUSED_ATTENTION)            # no lowering open
+    with tracing.lowering() as first:
+        tracing.note(tracing.FUSED_ATTENTION)
+        with tracing.lowering() as inner:
+            tracing.note(tracing.FUSED_ATTENTION)
+        tracing.note(tracing.CHUNKED_ATTENTION)
+    tracing.register("train_step", object(), compile_s=0.1, notes=first)
+    with tracing.lowering() as second:
+        pass
+    tracing.register("other_step", object(), compile_s=0.1, notes=second)
+    assert tracing.notes("train_step") == {tracing.FUSED_ATTENTION: 2,
+                                           tracing.CHUNKED_ATTENTION: 1}
+    assert inner == {tracing.FUSED_ATTENTION: 1}
+    assert tracing.notes("other_step") == {}
+    assert tracing.notes("not_registered") == {}
+    tracing.note(tracing.CHUNKED_ATTENTION)          # after: counts nowhere
+    assert tracing.notes("train_step")[tracing.CHUNKED_ATTENTION] == 1
+
+
 def test_a_step_without_the_scopes_reads_as_an_empty_table(monkeypatch):
     monkeypatch.setattr(tracing, "_PROGRAMS", {})
 
