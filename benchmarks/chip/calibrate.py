@@ -14,8 +14,12 @@ One JSON line per seed and reading on standard output:
               a serving cell reads it on the sequences the program
               served, so it needs --program too;
   --faults    training: the reference with each fault of
-              ``gpt2_ref.FAULTS`` planted in its steps, or those named
+              ``reference.FAULTS`` planted in its steps, or those named
               (``--faults half_batch``).
+
+The reference is ``chipbench/reference.py`` driving the mathematics of
+the family that the cell's configuration names, so every family's
+limits come from the same control and the same faults.
 
 Needs the chips the cell asks for, like ``run.py``.
 """
@@ -32,7 +36,7 @@ sys.path.insert(0, HERE)
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(HERE)),
                                 "src"))
 
-from chipbench import check, gen, gpt2_ref, spec  # noqa: E402
+from chipbench import check, gen, reference, spec  # noqa: E402
 
 
 def emit(**fields) -> None:
@@ -54,8 +58,9 @@ def train_seed(cell, seed, args):
     opt, z = traffic["optimizer"], traffic["z_loss"]
     batches = gen.TrainBatches(traffic, cfg["vocab_size"], seed)
     feed = [batches.batch_at(i) for i in range(traffic["check_steps"])]
-    steps = lambda **kw: gpt2_ref.train_steps(
-        cfg, opt, z, seed, feed, rows=traffic["reference_rows"], **kw)
+    steps = lambda **kw: reference.train_steps(
+        cell.family, cfg, opt, z, seed, feed,
+        rows=traffic["reference_rows"], **kw)
     ref = None
     if args.program:
         from chipbench import cell_train
@@ -83,9 +88,9 @@ def serve_seed(cell, seed, args):
     emit(seed=seed, kind="program", readings=out["readings"],
          setup_s=out["setup_s"], served=sum(len(s) for _, s in out["sample"]))
     if args.control:
-        gaps = gpt2_ref.served_gaps(cell.config, seed,
-                                    cell.traffic["weight_dtype"],
-                                    out["sample"], control=True)
+        gaps = reference.served_gaps(cell.family, cell.config, seed,
+                                     cell.traffic["weight_dtype"],
+                                     out["sample"], control=True)
         emit(seed=seed, kind="control", readings={"served_gap": max(gaps)})
 
 
@@ -97,10 +102,11 @@ def main(argv=None) -> int:
     ap.add_argument("--program", action="store_true")
     ap.add_argument("--control", action="store_true")
     ap.add_argument("--faults", nargs="?", const="all", default="",
-                    help="comma-separated faults of gpt2_ref.FAULTS; "
-                    "all of them if none is named")
+                    help="comma-separated faults of "
+                    f"{', '.join(reference.FAULTS[1:])}; all of them if "
+                    "none is named")
     args = ap.parse_args(argv)
-    args.faults = (gpt2_ref.FAULTS[1:] if args.faults == "all"
+    args.faults = (reference.FAULTS[1:] if args.faults == "all"
                    else [f for f in args.faults.split(",") if f])
     args.require_tpu = True
     cell = spec.load_cell(args.workload)

@@ -20,7 +20,7 @@ from typing import Any, Dict, List, Tuple
 
 import numpy as np
 
-from chipbench import counts, gen, gpt2_ref, harness, tracefile
+from chipbench import counts, gen, harness, reference, tracefile
 from chipbench.spec import Cell
 
 KV_ENGINE = {"unquantized": "fp32", "int8": "int8"}
@@ -73,13 +73,13 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t0: float, *,
     import jax
     import jax.numpy as jnp
 
-    from repro.models import Model, cast_params
+    from repro.models import cast_params
     from repro.serve import ContinuousEngine
 
     cfg, traffic = cell.config, cell.traffic
     devices = harness.chips_for(cell, require_tpu)
     plan, mesh = harness.make_mesh(traffic, devices)
-    model = Model(harness.model_config(cfg))
+    model = harness.program_model(cell)
     weight_dtype = jnp.dtype(traffic["weight_dtype"])
     with jax.set_mesh(mesh):
         params = jax.jit(lambda k: cast_params(model.init(k), weight_dtype))(
@@ -108,7 +108,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t0: float, *,
         kv_itemsize = jnp.dtype(cfg["compute_dtype"]).itemsize \
             if traffic["kv_cache"] == "unquantized" else 1
         meter = DecodeMeter(eng, profile, param_bytes,
-                            counts.kv_bytes_per_token(cfg, kv_itemsize))
+                            cell.family.program.kv_bytes_per_token(
+                                cfg, kv_itemsize))
 
     t_open = time.perf_counter()
     setup_s = t_open - t0
@@ -155,7 +156,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t0: float, *,
         out["breakdown"] = tracefile.breakdown(ctx.trace, ctx.lo, ctx.hi)
 
     sample = _check_sample(asked, served, traffic["check_requests"], seed)
-    gaps = gpt2_ref.served_gaps(cfg, seed, traffic["weight_dtype"], sample)
+    gaps = reference.served_gaps(cell.family, cfg, seed,
+                                 traffic["weight_dtype"], sample)
     out["readings"] = {"served_gap": max(gaps) if gaps else float("inf"),
                        "unfinished": float(len(failed))}
     out["sample"] = sample

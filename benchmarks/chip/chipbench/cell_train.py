@@ -19,7 +19,7 @@ import sys
 import time
 from typing import Any, Dict, Optional
 
-from chipbench import check, counts, gen, gpt2_ref, harness, tracefile
+from chipbench import check, counts, gen, harness, reference, tracefile
 from chipbench.spec import Cell
 
 
@@ -110,8 +110,8 @@ class StepClock:
             self.grad = {jax.tree_util.keystr(p): float(v) / (1 - self.beta1)
                          for p, v in m}
         if i == self.n_check:
-            self.change = gpt2_ref.change_norms(jax.device_get(params),
-                                                 self.p0)
+            self.change = reference.change_norms(jax.device_get(params),
+                                                  self.p0)
             self.p0 = None
 
 
@@ -139,7 +139,6 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t0: float, *,
     import jax
 
     from repro.configs.base import TrainConfig
-    from repro.models import Model
     from repro.train import train
 
     cfg, traffic = cell.config, cell.traffic
@@ -152,7 +151,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t0: float, *,
         total_steps=opt["total_steps"], schedule=opt["schedule"],
         seed=seed, microbatches=traffic["microbatches"], remat=True)
     plan, mesh = harness.make_mesh(traffic, devices)
-    model = Model(harness.model_config(cfg))
+    model = harness.program_model(cell)
     treedef = jax.tree.structure(jax.eval_shape(model.init,
                                                 jax.random.key(0)))
     batches = gen.TrainBatches(traffic, cfg["vocab_size"], seed)
@@ -185,16 +184,16 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t0: float, *,
         ctx = harness.trace_context(
             cell, profile.path, devices, "train.step",
             kind="train", tokens_per_step=tokens_per_step,
-            flops_per_token=counts.train_flops_per_token(cfg,
-                                                         traffic["seq"]))
+            flops_per_token=counts.train_flops_per_token(
+                cell.family, cfg, traffic["seq"]))
         out["layer_metrics"] = harness.layer_metrics(cell, ctx)
         out["device"].update(harness.traced_device(ctx))
         out["breakdown"] = tracefile.breakdown(ctx.trace, ctx.lo, ctx.hi)
 
     prog = {"losses": losses[:clock.n_check], "grad": clock.grad,
             "change": clock.change}
-    ref = gpt2_ref.train_steps(
-        cfg, opt, traffic["z_loss"], seed,
+    ref = reference.train_steps(
+        cell.family, cfg, opt, traffic["z_loss"], seed,
         [batches.batch_at(i) for i in range(clock.n_check)],
         rows=traffic["reference_rows"])
     out["readings"] = check.train_readings(prog, ref)

@@ -1,15 +1,18 @@
 """Operations and bytes the algorithm needs, from shapes alone.
 
 These are the yardstick's own counts: a later change to the program
-cannot alter them.  They count what GPT-2's mathematics requires, not
-what the program happens to execute, so recomputation (remat) and work
-spent on masked-out attention scores are not counted.
+cannot alter them.  They count what a family's mathematics requires,
+not what the program happens to execute, so recomputation (remat) and
+work spent on masked-out attention scores are not counted.  The
+per-family formulas (forward FLOPs per token, bytes per cached token,
+serving FLOPs) are in ``families/<family>.py``; what holds for every
+family is here.
 """
 from __future__ import annotations
 
 import json
 import os
-from typing import Dict, Iterable
+from typing import Any, Dict, Iterable
 
 from chipbench.spec import HERE
 
@@ -26,34 +29,11 @@ def peaks(device_kind: str) -> Dict[str, float]:
     return table["devices"][device_kind]
 
 
-def forward_flops_per_token(cfg: Dict[str, int], seq: int) -> float:
-    """Forward FLOPs per token of a GPT-2 decoder over sequences of
-    ``seq`` tokens (2 FLOPs per multiply-add).
-
-    Per layer: the q, k, v and output projections (4 d^2 MACs), the MLP
-    (2 d d_ff MACs) and causal attention.  Causal attention counts half:
-    token i attends to i + 1 positions, so a sequence needs
-    d S (S + 1) / 2 MACs for the scores and as many for scores x values,
-    which is d (S + 1) MACs per token for the two together.  Then the
-    tied unembedding (d V MACs).  Embedding gathers, layer norms,
-    biases, GELU and the softmax are elementwise and not counted.
-    """
-    d, f = cfg["n_embd"], cfg["n_inner"]
-    per_layer = 4 * d * d + 2 * d * f + d * (seq + 1)
-    return 2.0 * (cfg["n_layer"] * per_layer + d * cfg["vocab_size"])
-
-
-def train_flops_per_token(cfg: Dict[str, int], seq: int) -> float:
+def train_flops_per_token(family, cfg: Dict[str, Any], seq: int) -> float:
     """Forward plus backward: the backward pass needs twice the forward's
     multiply-adds (one product for the input's gradient, one for the
     weight's)."""
-    return 3.0 * forward_flops_per_token(cfg, seq)
-
-
-def kv_bytes_per_token(cfg: Dict[str, int], kv_itemsize: int) -> int:
-    """Bytes one cached token holds over all layers: a key and a value
-    row of every head."""
-    return cfg["n_layer"] * 2 * cfg["n_embd"] * kv_itemsize
+    return 3.0 * family.program.forward_flops_per_token(cfg, seq)
 
 
 def decode_step_bytes(param_bytes: int, fills: Iterable[int],
@@ -65,21 +45,3 @@ def decode_step_bytes(param_bytes: int, fills: Iterable[int],
     cache allocates, lets a cache that reads only what is filled show
     as a gain."""
     return int(param_bytes) + int(sum(fills)) * int(kv_token_bytes)
-
-
-def serve_flops(cfg: Dict[str, int], requests: Iterable) -> float:
-    """Forward FLOPs the algorithm needs to serve ``requests``, each a
-    (prompt length P, tokens served n) pair: the prompt's layers over all
-    P positions with causal attention, one unembedding for the first
-    token, then n - 1 decode steps, the j-th attending to P + j
-    positions."""
-    d, f, L = cfg["n_embd"], cfg["n_inner"], cfg["n_layer"]
-    dense = 4 * d * d + 2 * d * f          # MACs per token per layer
-    head = d * cfg["vocab_size"]
-    macs = 0
-    for P, n in requests:
-        macs += L * (P * dense + d * P * (P + 1)) + head
-        steps = max(int(n) - 1, 0)
-        ctx = steps * P + steps * (steps + 1) // 2   # sum of P + j
-        macs += steps * (L * dense + head) + L * 2 * d * ctx
-    return 2.0 * macs

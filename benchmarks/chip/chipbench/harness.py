@@ -1,6 +1,6 @@
-"""What the training and serving cells share: the program's model and
-optimizer settings from a cell's files, the device record, the profiler
-window and the per-layer metrics read from it."""
+"""What the training and serving cells share: the program's model from
+a cell's family, its mesh and plan from the traffic file, the device
+record, the profiler window and the per-layer metrics read from it."""
 from __future__ import annotations
 
 import glob
@@ -32,22 +32,11 @@ def chips_for(cell: Cell, require_tpu: bool = True) -> List[Any]:
     return devices[:cell.chips]
 
 
-def model_config(cfg: Dict[str, Any]):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-    if cfg["activation_function"] != "gelu_new" or \
-            cfg["position_embedding"] != "learned":
-        raise ValueError("the harness runs GPT-2 blocks: tanh GELU and "
-                         "learned positions")
-    return ModelConfig(
-        name=cfg["name"], family="dense", n_layers=cfg["n_layer"],
-        d_model=cfg["n_embd"], n_heads=cfg["n_head"],
-        n_kv_heads=cfg["n_head"], d_ff=cfg["n_inner"],
-        vocab_size=cfg["vocab_size"], max_seq_len=cfg["n_positions"],
-        rope_theta=0.0, norm="layernorm",
-        norm_eps=cfg["layer_norm_epsilon"], activation="gelu",
-        tie_embeddings=cfg["tie_word_embeddings"],
-        dtype=cfg["compute_dtype"], source=cfg["source"])
+def program_model(cell: Cell):
+    """The program's ``Model`` of the cell's configuration, as its family
+    reads the file."""
+    from repro.models import Model
+    return Model(cell.family.program.program_config(cell.config))
 
 
 def make_mesh(traffic: Dict[str, Any], devices: Sequence[Any]):
@@ -130,7 +119,7 @@ def trace_context(cell: Cell, path: str, devices: Sequence[Any],
                   window_span: str, **extra) -> SimpleNamespace:
     """What a metric reader reads: the trace cut to the chips the cell
     uses, the window (the first to the last closed ``window_span``), the
-    chips' peaks and whatever the cell adds."""
+    chips' peaks, the cell's family and whatever the cell adds."""
     trace = tracefile.load(path)
     used = {f"/device:TPU:{d.id}" for d in devices}
     trace.ops = {k: v for k, v in trace.ops.items() if k in used}
@@ -141,7 +130,7 @@ def trace_context(cell: Cell, path: str, devices: Sequence[Any],
     lo, hi = windows[0][0], windows[-1][1]
     return SimpleNamespace(
         trace=trace, lo=lo, hi=hi, window_s=(hi - lo) / 1e9,
-        windows=windows, chips=len(devices),
+        windows=windows, chips=len(devices), family=cell.family,
         peaks=counts.peaks(devices[0].device_kind), **extra)
 
 

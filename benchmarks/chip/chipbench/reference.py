@@ -1,13 +1,14 @@
-"""Plain GPT-2 in float32: the reference that decides ``correct``.
+"""The plain float32 reference that decides ``correct``, for any family.
+
+A family's mathematics (``references/<family>.py``, on ``Cell.family``
+as ``family.math``) gives ``init(cfg, key)``, ``logits(cfg, params,
+tokens, mm, remat=False)`` and ``loss_sums(cfg, params, tokens, labels,
+mm)``.  This module drives it: AdamW steps over blocks of rows, the
+norms the checks compare, the logit gaps of served tokens, the control
+and the planted faults.  So every family is held to the same rules.
 
 It imports nothing of the program and takes nothing the program made.
-It draws the weights from the seed itself, by the initialisation the
-configuration states (truncated normal within 3 standard deviations:
-std 1/sqrt(fan-in) for projections, 0.02 for the embeddings; zero
-biases, unit layer-norm scales), with the same key splits, so that both
-sides start from the same numbers.  Every matrix product runs at
-``Precision.HIGHEST``.
-
+Every matrix product goes through ``mm`` at ``Precision.HIGHEST``.
 ``precision="float8"`` is the control: every matrix product takes its
 operands rounded to float8 e4m3 with one absmax scale per tensor, as a
 change to fp8 matmuls would.  Gradients pass the rounding straight
@@ -43,107 +44,6 @@ def _mm(precision: str, spec: str, a, b):
     return jnp.einsum(spec, a, b, precision=HIGHEST)
 
 
-def dims(cfg: Dict[str, Any]) -> Tuple[int, int, int, int, int, int]:
-    d, h = cfg["n_embd"], cfg["n_head"]
-    return (cfg["n_layer"], d, h, d // h, cfg["n_inner"],
-            cfg["vocab_size"])
-
-
-def init(cfg: Dict[str, Any], key) -> Dict[str, Any]:
-    """Float32 weights drawn from ``jax.random.key(seed)`` (call under
-    ``jit``)."""
-    L, d, H, hd, f, V = dims(cfg)
-    tn = partial(jax.random.truncated_normal, lower=-3.0, upper=3.0)
-
-    def dense(k, shape, fan_in):
-        return (1.0 / math.sqrt(fan_in)) * tn(k, shape=shape)
-
-    def norm():
-        return {"scale": jnp.ones((d,)), "bias": jnp.zeros((d,))}
-
-    def block(k):
-        r = jax.random.split(k, 4)
-        m = jax.random.split(r[2], 3)
-        a = jax.random.split(r[3], 4)
-        return {
-            "norm1": norm(), "norm2": norm(),
-            "mlp": {"w_up": dense(m[0], (d, f), d),
-                    "b_up": jnp.zeros((f,)),
-                    "w_down": dense(m[1], (f, d), f),
-                    "b_down": jnp.zeros((d,))},
-            "attn": {"wq": dense(a[0], (d, H, hd), d),
-                     "wk": dense(a[1], (d, H, hd), d),
-                     "wv": dense(a[2], (d, H, hd), d),
-                     "wo": dense(a[3], (H, hd, d), H * hd),
-                     "bq": jnp.zeros((H, hd)), "bk": jnp.zeros((H, hd)),
-                     "bv": jnp.zeros((H, hd)), "bo": jnp.zeros((d,))},
-        }
-
-    r = jax.random.split(key, 8)
-    return {
-        "embed": {"table": 0.02 * tn(r[0], shape=(V, d))},
-        "final_norm": norm(),
-        "pos_embed": {"table": 0.02 * tn(r[3], shape=(cfg["n_positions"],
-                                                       d))},
-        "layers": jax.vmap(block)(jax.random.split(r[4], L)),
-    }
-
-
-def _ln(x, p, eps):
-    mu = jnp.mean(x, -1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mu), -1, keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
-
-
-def _gelu(x):
-    return 0.5 * x * (1.0 + jnp.tanh(math.sqrt(2.0 / math.pi)
-                                     * (x + 0.044715 * x ** 3)))
-
-
-def logits(cfg: Dict[str, Any], params, tokens, precision: str = "float32",
-           remat: bool = False):
-    """[b, S] token ids -> [b, S, V] float32 logits, causal attention."""
-    L, d, H, hd, f, V = dims(cfg)
-    eps = cfg["layer_norm_epsilon"]
-    mm = partial(_mm, precision)
-    S = tokens.shape[1]
-    x = params["embed"]["table"][tokens] + params["pos_embed"]["table"][:S]
-    causal = jnp.tril(jnp.ones((S, S), bool))
-
-    def layer(x, p):
-        a = p["attn"]
-        h = _ln(x, p["norm1"], eps)
-        q = mm("bsd,dhk->bshk", h, a["wq"]) + a["bq"]
-        k = mm("bsd,dhk->bshk", h, a["wk"]) + a["bk"]
-        v = mm("bsd,dhk->bshk", h, a["wv"]) + a["bv"]
-        s = mm("bshk,bthk->bhst", q, k) / math.sqrt(hd)
-        s = jnp.where(causal, s, -jnp.inf)
-        o = mm("bhst,bthk->bshk", jax.nn.softmax(s, axis=-1), v)
-        x = x + mm("bshk,hkd->bsd", o, a["wo"]) + a["bo"]
-        m = p["mlp"]
-        h = _ln(x, p["norm2"], eps)
-        u = _gelu(mm("bsd,df->bsf", h, m["w_up"]) + m["b_up"])
-        return x + mm("bsf,fd->bsd", u, m["w_down"]) + m["b_down"], None
-
-    x, _ = jax.lax.scan(jax.checkpoint(layer) if remat else layer, x,
-                        params["layers"])
-    x = _ln(x, params["final_norm"], eps)
-    return mm("bsd,vd->bsv", x, params["embed"]["table"])
-
-
-def _loss_sums(cfg, precision, params, tokens, labels):
-    """Summed next-token cross-entropy and squared log-normaliser over
-    the labels that count (>= 0)."""
-    lg = logits(cfg, params, tokens, precision, remat=True)[:, :-1]
-    lab = labels[:, 1:]
-    mask = lab >= 0
-    lse = jax.nn.logsumexp(lg, axis=-1)
-    picked = jnp.take_along_axis(lg, jnp.maximum(lab, 0)[..., None],
-                                 axis=-1)[..., 0]
-    nll = jnp.sum(jnp.where(mask, lse - picked, 0.0))
-    return nll, jnp.sum(jnp.where(mask, jnp.square(lse), 0.0))
-
-
 def _decays(path) -> bool:
     """AdamW's weight decay skips the layer-norm scales and biases."""
     names = [str(getattr(p, "key", "")) for p in path]
@@ -166,12 +66,14 @@ def change_norms(now, before) -> Dict[str, float]:
         for (p, a), b in zip(flat, jax.tree.leaves(before))}
 
 
-def grad_accumulator(cfg: Dict[str, Any], z_loss: float,
+def grad_accumulator(maths, cfg: Dict[str, Any], z_loss: float,
                      precision: str = "float32"):
     """``acc, params, tokens, labels -> acc`` with the gradient of a block
     of rows' summed loss added to ``acc = (grads, nll, z)`` in place."""
+    mm = partial(_mm, precision)
+
     def block_loss(p, tokens, labels):
-        nll, zsq = _loss_sums(cfg, precision, p, tokens, labels)
+        nll, zsq = maths.loss_sums(cfg, p, tokens, labels, mm)
         return nll + z_loss * zsq, (nll, zsq)
 
     @partial(jax.jit, donate_argnums=(0,))
@@ -222,14 +124,14 @@ _negate = jax.jit(lambda new, old: jax.tree.map(lambda a, b: 2 * b - a,
 
 
 @lru_cache(maxsize=None)
-def _step_programs(cfg_json: str, opt_json: str, z_loss: float,
+def _step_programs(maths, cfg_json: str, opt_json: str, z_loss: float,
                    precision: str):
     """The jitted init, gradient and AdamW programs of one configuration,
     kept for the process: ``calibrate.py`` runs many seeds and variants
     through them and compiles each once."""
     cfg = json.loads(cfg_json)
-    return (jax.jit(partial(init, cfg)),
-            grad_accumulator(cfg, z_loss, precision),
+    return (jax.jit(partial(maths.init, cfg)),
+            grad_accumulator(maths, cfg, z_loss, precision),
             adamw_step(json.loads(opt_json)))
 
 
@@ -237,13 +139,15 @@ FAULTS = ("", "half_batch", "state_unchanged", "moments_not_carried",
           "update_negated")
 
 
-def train_steps(cfg: Dict[str, Any], opt: Dict[str, Any], z_loss: float,
-                seed: int, batches: Sequence[Dict[str, np.ndarray]], *,
+def train_steps(family, cfg: Dict[str, Any], opt: Dict[str, Any],
+                z_loss: float, seed: int,
+                batches: Sequence[Dict[str, np.ndarray]], *,
                 precision: str = "float32", rows: int = 1,
                 fault: str = "") -> Dict[str, Any]:
-    """AdamW steps over ``batches`` from the seed's weights: each step's
-    loss, every leaf's norm of the first (clipped) gradient, and every
-    leaf's norm of the parameters' change over all the steps.
+    """AdamW steps of ``family``'s mathematics over ``batches`` from the
+    seed's weights: each step's loss, every leaf's norm of the first
+    (clipped) gradient, and every leaf's norm of the parameters' change
+    over all the steps.
 
     The gradient of a step is summed over blocks of ``rows`` rows, so the
     reference fits beside nothing else on one chip.  ``fault`` plants one
@@ -257,8 +161,8 @@ def train_steps(cfg: Dict[str, Any], opt: Dict[str, Any], z_loss: float,
     if fault not in FAULTS:
         raise ValueError(f"unknown fault {fault!r}")
     init_fn, accumulate, adamw = _step_programs(
-        json.dumps(cfg, sort_keys=True), json.dumps(opt, sort_keys=True),
-        z_loss, precision)
+        family.math, json.dumps(cfg, sort_keys=True),
+        json.dumps(opt, sort_keys=True), z_loss, precision)
     params = init_fn(jax.random.key(seed))
     p0 = jax.device_get(params)
     m, v = _zeros(params), _zeros(params)
@@ -316,7 +220,7 @@ def lr_at(opt: Dict[str, Any], step: int) -> float:
     return opt["learning_rate"] * warm * decay
 
 
-def served_gaps(cfg: Dict[str, Any], seed: int, weight_dtype: str,
+def served_gaps(family, cfg: Dict[str, Any], seed: int, weight_dtype: str,
                 seqs: Sequence[Tuple[np.ndarray, np.ndarray]], *,
                 control: bool = False, rows: int = 4) -> List[float]:
     """For each (prompt, served tokens): the widest gap by which a served
@@ -325,18 +229,20 @@ def served_gaps(cfg: Dict[str, Any], seed: int, weight_dtype: str,
     ``control``: instead of the served token, the token that the float8
     reference puts first at each of those positions.
     """
-    params = jax.jit(partial(init, cfg))(jax.random.key(seed))
+    maths = family.math
+    params = jax.jit(partial(maths.init, cfg))(jax.random.key(seed))
     if weight_dtype != "float32":
         params = jax.jit(lambda t: jax.tree.map(
             lambda x: x.astype(weight_dtype).astype(jnp.float32), t))(params)
-    S = cfg["n_positions"]
+    S = family.program.positions(cfg)
 
     @jax.jit
     def gaps(params, tokens, targets, valid):
-        ref = logits(cfg, params, tokens)
+        ref = maths.logits(cfg, params, tokens, partial(_mm, "float32"))
         best = jnp.max(ref, axis=-1)
         if control:
-            targets = jnp.argmax(logits(cfg, params, tokens, "float8"), -1)
+            targets = jnp.argmax(maths.logits(
+                cfg, params, tokens, partial(_mm, "float8")), -1)
         got = jnp.take_along_axis(ref, targets[..., None], axis=-1)[..., 0]
         return jnp.max(jnp.where(valid, best - got, -jnp.inf), axis=-1)
 

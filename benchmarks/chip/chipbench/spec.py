@@ -3,24 +3,45 @@
 Everything that belongs to one configuration, traffic mix, per-layer
 metric or cell lives in a file of its own, found by name:
 
-  configs/<config>.json     the model as it is run (``file`` in the entry)
+  configs/<config>.json     the model as it is run (``file`` in the entry);
+                            its ``family`` names the two files below
+  families/<family>.py      the program's config, counts and rehearsal
+                            sizes for that architecture
+  references/<family>.py    its plain float32 mathematics
   traffic/<traffic>.json    the parameters the one generator reads
   limits/<workload>.json    the limits that decide ``correct``
   metrics/<metric>.py       the reader of one per-layer metric
 
-So a new cell, configuration, traffic mix or metric is new files and new
-entries in ``BENCHMARK.json``, never an edit of a file that is there.
+So a new cell, configuration, architecture, traffic mix or metric is new
+files and new entries in ``BENCHMARK.json``, never an edit of a file that
+is there.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
 import os
+import re
 from dataclasses import dataclass
+from functools import lru_cache
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(os.path.dirname(HERE))
+
+
+class SpecError(ValueError):
+    """A file that ``BENCHMARK.json`` leads to is missing or incomplete."""
+
+
+@dataclass(frozen=True)
+class Family:
+    """One architecture: ``program`` is ``families/<name>.py``, ``math``
+    is ``references/<name>.py``."""
+    name: str
+    program: ModuleType
+    math: ModuleType
 
 
 @dataclass(frozen=True)
@@ -30,6 +51,7 @@ class Cell:
     config_name: str
     traffic_name: str
     config: Dict[str, Any]
+    family: Family
     traffic: Dict[str, Any]
     limits: Dict[str, float]
     end_to_end: List[Dict[str, Any]]   # the metrics this cell reports
@@ -43,6 +65,28 @@ def _load_json(path: str) -> Dict[str, Any]:
 
 def _reports(metric: Dict[str, Any], cell: str) -> bool:
     return cell in metric.get("workloads", [cell])
+
+
+@lru_cache(maxsize=None)
+def _module(path: str, name: str) -> ModuleType:
+    """The module of the file ``path``, loaded once per process."""
+    if not os.path.isfile(path):
+        raise SpecError(f"no such file: {path}")
+    mod_spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
+def load_family(name: str, bench_dir: str = HERE) -> Family:
+    """The family ``name``: ``families/<name>.py`` and
+    ``references/<name>.py`` under ``bench_dir``."""
+    if not re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}", str(name)):
+        raise SpecError(f"family {name!r} is not a name")
+    return Family(name, *(
+        _module(os.path.join(bench_dir, kind, f"{name}.py"),
+                f"chipbench_{kind}_{name}")
+        for kind in ("families", "references")))
 
 
 def load_cell(workload: str, root: str = ROOT,
@@ -60,8 +104,11 @@ def load_cell(workload: str, root: str = ROOT,
         raise KeyError(f"no workload {workload!r} in BENCHMARK.json; "
                        f"known: {sorted(cells)}")
     w = entry or cells[workload]
-    config = _load_json(os.path.join(bench_dir, "configs",
-                                     f"{w['config']}.json"))
+    config_path = os.path.join(bench_dir, "configs", f"{w['config']}.json")
+    config = _load_json(config_path)
+    if "family" not in config:
+        raise SpecError(f"{config_path} names no family: give it "
+                        f"\"family\", the name of a families/<name>.py")
     traffic = _load_json(os.path.join(bench_dir, "traffic",
                                       f"{w['traffic']}.json"))
     limits = _load_json(os.path.join(bench_dir, "limits",
@@ -69,15 +116,12 @@ def load_cell(workload: str, root: str = ROOT,
     e2e = [m for m in spec["end_to_end"] if _reports(m, workload)]
     layer = [m for m in spec["per_layer"] if _reports(m, workload)]
     return Cell(workload, int(w["chips"]), w["config"], w["traffic"],
-                config, traffic, limits, e2e, layer)
+                config, load_family(config["family"], bench_dir), traffic,
+                limits, e2e, layer)
 
 
 def metric_reader(name: str, bench_dir: str = HERE
                   ) -> Callable[[Any], Optional[float]]:
     """``read(ctx)`` of ``metrics/<name>.py``."""
-    path = os.path.join(bench_dir, "metrics", f"{name}.py")
-    mod_spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return _module(os.path.join(bench_dir, "metrics", f"{name}.py"),
+                   f"chipbench_metric_{name.replace('.', '_')}").read

@@ -1,10 +1,11 @@
 """Model FLOP utilisation of the whole training step, from the trace.
 
 The FLOPs of the traced window's whole steps (``counts.
-train_flops_per_token``: forward and backward, causal attention counted
-half, recomputation not counted) over the window's length times the
-chips times each chip's bf16 peak.  The window runs from the start of
-the first traced step to the end of the last, host gaps included.
+train_flops_per_token`` of the cell's family: forward and backward,
+causal attention counted half, recomputation not counted) over the
+window's length times the chips times each chip's bf16 peak.  The
+window runs from the start of the first traced step to the end of the
+last, host gaps included.
 """
 
 

@@ -6,17 +6,20 @@
 
 Loads and warms up the cell (``setup_s``), measures for ``--seconds``,
 checks what the timed path produced against the plain reference
-(``chipbench/gpt2_ref.py``), and prints one JSON line last on standard
-output: ``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's
-end-to-end metrics, or with ``--trace 1`` its per-layer metrics, read
-from a profiler trace of the window's first part), ``device``,
-``breakdown`` (traced runs) and ``checks`` (each number compared, beside
-its limit), which comes last.  The same numbers are the last lines of
-standard error.
+(``chipbench/reference.py`` driving ``references/<family>.py``, the
+family that the cell's configuration names), and prints one JSON line
+last on standard output: ``correct``, ``attempted``, ``failed``,
+``metrics`` (the cell's end-to-end metrics, or with ``--trace 1`` its
+per-layer metrics, read from a profiler trace of the window's first
+part), ``device``, ``breakdown`` (traced runs) and ``checks`` (each
+number compared, beside its limit), which comes last.  The same
+numbers are the last lines of standard error.
 
 It runs on the chips of the machine it is started on and needs a TPU:
 without one, or with fewer chips than the cell asks for, it exits with
-code 2 and prints no result.  JAX's compilation cache is
+code 2 and prints no result, as it does where a file that
+``BENCHMARK.json`` leads to is missing: a configuration without a
+``family``, or a family without its files.  JAX's compilation cache is
 ``$JAX_COMPILATION_CACHE_DIR`` if set, else ``<checkout>/.jax_cache``.
 """
 import time
@@ -74,7 +77,7 @@ def main(argv=None) -> int:
     args = parse_args(argv)
     try:
         cell = spec.load_cell(args.workload)
-    except (OSError, KeyError) as e:
+    except (OSError, KeyError, spec.SpecError) as e:
         print(f"run.py: {e}", file=sys.stderr)
         return 2
     from repro.launch import enable_compile_cache

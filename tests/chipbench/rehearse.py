@@ -5,8 +5,8 @@ planted in the timed path, for the tests beside this file.
         gpt2m-train-1chip [fault]
 
 prints the run's result line.  The cell keeps its traffic mix, plan,
-window and checks; only the widths, the batch and the slots shrink, and
-the harness's look for a TPU is skipped.
+window and checks; only the widths (its family's ``TINY``), the batch
+and the slots shrink, and the harness's look for a TPU is skipped.
 """
 from __future__ import annotations
 
@@ -24,8 +24,6 @@ for p in (BENCH, os.path.join(ROOT, "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-TINY = dict(n_layer=2, n_embd=64, n_head=4, n_inner=256, vocab_size=512,
-            n_positions=64)
 # cells rehearsed here before BENCHMARK.json holds them
 ENTRIES = {
     "gpt2m-serve-batch": {"name": "gpt2m-serve-batch", "config": "gpt2m",
@@ -33,15 +31,17 @@ ENTRIES = {
 }
 
 
-def tiny_cell(workload: str, root: str = ROOT):
+def tiny_cell(workload: str, root: str = ROOT, entry=None):
     import numpy as np
 
     from chipbench import spec
-    cell = spec.load_cell(workload, root, ENTRIES.get(workload))
+    cell = spec.load_cell(workload, root, entry or ENTRIES.get(workload))
+    config = dict(cell.config, **cell.family.program.TINY)
     traffic = dict(cell.traffic)
     if traffic["kind"] == "train":
         traffic.update(batch=4, seq=32, reference_rows=2,
-                       documents=dict(traffic["documents"], eos_id=511,
+                       documents=dict(traffic["documents"],
+                                      eos_id=config["vocab_size"] - 1,
                                       median_tokens=10))
     else:
         traffic.update(slots=4, max_len=64, requests_per_wave=10,
@@ -49,7 +49,7 @@ def tiny_cell(workload: str, root: str = ROOT):
                        prompt=dict(median=12, sigma=0.5, min=4, max=40),
                        output=dict(median=6, sigma=0.5, min=2, max=16))
     return dataclasses.replace(
-        cell, config=dict(cell.config, **TINY), traffic=traffic,
+        cell, config=config, traffic=traffic,
         chips=int(np.prod(traffic["mesh"])))
 
 
@@ -121,12 +121,13 @@ def planted(fault: str):
 
 
 def rehearse(workload: str, fault: str = "", seed: int = 2 ** 31 + 7,
-             seconds: float = 0.0) -> dict:
+             seconds: float = 0.0, root: str = ROOT, entry=None) -> dict:
     """The result line of one run.  A window of 0 s holds one training
-    step or one serving wave, all of whose requests are checked."""
+    step or one serving wave, all of whose requests are checked.
+    ``root`` and ``entry`` are as ``spec.load_cell`` takes them."""
     sys.path.insert(0, BENCH)
     import run
-    cell = tiny_cell(workload)
+    cell = tiny_cell(workload, root, entry)
     with planted(fault):
         return run.measure(cell, seed, seconds, False, time.perf_counter(),
                            require_tpu=False)

@@ -21,8 +21,9 @@ import rehearse  # noqa: E402
 
 ROOT, BENCH = rehearse.ROOT, rehearse.BENCH
 
-from chipbench import check, counts, gen, gpt2_ref, spec, tracefile  # noqa
+from chipbench import check, counts, gen, reference, spec, tracefile  # noqa
 
+GPT2 = spec.load_family("gpt2")
 GPT2M = {"n_layer": 24, "n_embd": 1024, "n_head": 16, "n_inner": 4096,
          "vocab_size": 50257, "n_positions": 1024}
 
@@ -36,22 +37,23 @@ def test_train_flops_hand_count_gpt2m():
     per_layer = 4 * 1024 ** 2 + 2 * 1024 * 4096 + 1024 * 1025
     assert per_layer == 13_632_512
     fwd = 2 * (24 * per_layer + 1024 * 50257)
-    assert counts.forward_flops_per_token(GPT2M, 1024) == fwd == 757_286_912
-    assert counts.train_flops_per_token(GPT2M, 1024) == 3 * fwd
+    assert GPT2.program.forward_flops_per_token(GPT2M, 1024) == fwd \
+        == 757_286_912
+    assert counts.train_flops_per_token(GPT2, GPT2M, 1024) == 3 * fwd
 
 
 @pytest.mark.parametrize("seq", [128, 512, 1024])
 def test_train_flops_scale_with_sequence(seq):
     # doubling the sequence adds only attention: 2 d S per token per
     # layer, forward
-    a = counts.forward_flops_per_token(GPT2M, seq)
-    b = counts.forward_flops_per_token(GPT2M, 2 * seq)
+    a = GPT2.program.forward_flops_per_token(GPT2M, seq)
+    b = GPT2.program.forward_flops_per_token(GPT2M, 2 * seq)
     assert b - a == 2 * 24 * 1024 * seq
 
 
 @pytest.mark.parametrize("slots", [1, 16, 64])
 def test_decode_bytes_scale_with_filled_positions(slots):
-    kv = counts.kv_bytes_per_token(GPT2M, 2)
+    kv = GPT2.program.kv_bytes_per_token(GPT2M, 2)
     assert kv == 24 * 2 * 1024 * 2 == 96 * 1024
     params = 700_000_000
     one = counts.decode_step_bytes(params, [100] * slots, kv)
@@ -71,7 +73,7 @@ def test_serve_flops_match_a_token_by_token_count():
         macs += d * V                                # first token
         for j in range(1, n):                        # decode, P + j keys
             macs += L * (4 * d * d + 2 * d * f + 2 * d * (P + j)) + d * V
-    assert counts.serve_flops(cfg, reqs) == 2 * macs
+    assert GPT2.program.serve_flops(cfg, reqs) == 2 * macs
 
 
 def test_peaks_refuse_an_unknown_device():
@@ -177,7 +179,7 @@ def test_mfu_and_roofline_read_under_100_percent_on_known_busy_time():
         kind="train", trace=t, lo=0, hi=int(2e9), window_s=2.0,
         windows=[(0, 1, "train.step")] * 4, chips=1, peaks=peaks,
         tokens_per_step=8192,
-        flops_per_token=counts.train_flops_per_token(GPT2M, 1024))
+        flops_per_token=counts.train_flops_per_token(GPT2, GPT2M, 1024))
     mfu = spec.metric_reader("step_mfu.train")(ctx)
     assert mfu == pytest.approx(100 * 4 * 8192 * 2271860736
                                 / (2.0 * 197e12))
@@ -399,9 +401,9 @@ def _reference_steps(seed):
     cfg, traffic = cell.config, cell.traffic
     batches = gen.TrainBatches(traffic, cfg["vocab_size"], seed)
     feed = [batches.batch_at(i) for i in range(traffic["check_steps"])]
-    return lambda **kw: gpt2_ref.train_steps(
-        cfg, traffic["optimizer"], traffic["z_loss"], seed, feed,
-        rows=traffic["reference_rows"], **kw)
+    return lambda **kw: reference.train_steps(
+        cell.family, cfg, traffic["optimizer"], traffic["z_loss"], seed,
+        feed, rows=traffic["reference_rows"], **kw)
 
 
 def test_training_control_reads_far_from_the_program():
@@ -451,8 +453,8 @@ def test_serving_control_reads_far_from_the_program():
     sample = [(prompt, g.integers(0, cell.config["vocab_size"], n))
               for prompt, n in gen.wave(cell.traffic,
                                         cell.config["vocab_size"], seed, 1)]
-    control = max(gpt2_ref.served_gaps(
-        cell.config, seed, cell.traffic["weight_dtype"], sample,
-        control=True))
+    control = max(reference.served_gaps(
+        cell.family, cell.config, seed, cell.traffic["weight_dtype"],
+        sample, control=True))
     program = line["checks"]["served_gap"]["value"]
     assert control > 5 * program, (control, program)
